@@ -42,7 +42,9 @@ def run() -> list[str]:
     rows = []
     results = {}
     for ndev in (1, 2, 4, 8):
-        env = dict(os.environ, PYTHONPATH="src")
+        # a virtual-device study: the children run on the CPU backend,
+        # so none of them needs the chip this (parent) process may hold
+        env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
         out = subprocess.run(
             [sys.executable, "-c", _CHILD.format(ndev=ndev)],
             capture_output=True, text=True, timeout=900, env=env,

@@ -27,6 +27,8 @@ import sys
 import time
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from . import (
     bench_accuracy,
     bench_case_study,
@@ -55,6 +57,7 @@ SUITES = {
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="substring filter on suite name")

@@ -119,6 +119,11 @@ DATASET_ANALOGS = {
 }
 
 
-def make(name: str, seed: int = 0) -> TemporalGraph:
+def make(name: str, seed: int = 0, *, n_edges: int | None = None
+         ) -> TemporalGraph:
+    """Build an analog; ``n_edges`` replaces its CPU-scale edge count (e.g.
+    with the published Table-1 size) and keeps every other parameter."""
     gen, kwargs = DATASET_ANALOGS[name]
+    if n_edges is not None:
+        kwargs = {**kwargs, "n_edges": int(n_edges)}
     return gen(seed=seed, **kwargs)

@@ -15,17 +15,11 @@ import jax.numpy as jnp
 
 
 def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """shard_map across jax versions (stable ``jax.shard_map`` vs the
-    ``jax.experimental`` spelling), replication checking disabled — SPMD
-    bodies here create carries inside the shard, which the checker cannot
-    see through."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+    """``jax.shard_map`` with replication checking disabled — SPMD bodies
+    here create carries inside the shard, which the checker cannot see
+    through."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def compressed_psum_int8(x, axis_name, key):

@@ -1,31 +1,19 @@
 """Shared utilities for the Pallas kernels.
 
-One copy of the interpret-mode default: every kernel wrapper used to
-inline ``interpret = jax.default_backend() == "cpu"``, which made it
-impossible for CI or a benchmark to force a mode without threading an
-argument through every call site.  :func:`resolve_interpret` adds a
-``REPRO_PALLAS_INTERPRET`` environment override on top of the backend
-heuristic, so a single env var flips the whole kernel suite:
+One copy of the interpret-mode default (:func:`resolve_interpret`): an
+explicit ``interpret=`` argument at a call site wins; otherwise a kernel
+interprets exactly when the default backend is CPU, which has no Pallas
+lowering.  On an accelerator nothing but an explicit ``interpret=True``
+puts a kernel in the interpreter, so a chip run always executes the
+compiled (Mosaic) kernel it asked for.
 
-  * ``REPRO_PALLAS_INTERPRET=1`` (or ``true``/``yes``/``on``) — force the
-    Pallas interpreter everywhere (debugging a kernel on any device);
-  * ``REPRO_PALLAS_INTERPRET=0`` (or ``false``/``no``/``off``) — force
-    compiled lowering even on CPU (exercises the Triton/Mosaic pipeline);
-  * unset or ``auto`` — interpret exactly when the default backend is CPU
-    (the historical behavior: CPU has no Pallas lowering).
-
-An explicit ``interpret=`` argument at a call site still beats the env
-var — explicit beats derived everywhere in this codebase.
-
-The *silent* arm of the heuristic (unset/``auto`` on CPU) is a perf
-footgun: the interpreter is orders of magnitude slower than a compiled
-lowering, and nothing used to say it was active.  The first silent
-fallback per process now emits one ``RuntimeWarning`` plus a
+The CPU arm is a perf footgun: the interpreter is orders of magnitude
+slower than a compiled lowering.  The first silent fallback per process
+emits one ``RuntimeWarning`` plus a
 ``repro_kernel_interpret_fallbacks_total`` counter tick (every fallback
-counts; only the warning is once-per-process).  Explicit requests —
-``interpret=True`` or the env var — are intentional and never warn, and
-test runs (``PYTEST_CURRENT_TEST`` set) stay quiet: differential tests
-pin interpret mode on purpose.
+counts; only the warning is once-per-process).  Explicit requests never
+warn, and test runs (``PYTEST_CURRENT_TEST`` set) stay quiet: differential
+tests pin interpret mode on purpose.
 """
 
 from __future__ import annotations
@@ -35,12 +23,7 @@ import warnings
 
 import jax
 
-__all__ = ["INTERPRET_ENV", "note_trace", "resolve_interpret"]
-
-INTERPRET_ENV = "REPRO_PALLAS_INTERPRET"
-
-_TRUE = ("1", "true", "yes", "on")
-_FALSE = ("0", "false", "no", "off")
+__all__ = ["note_trace", "resolve_interpret"]
 
 _fallback_warned = False
 
@@ -59,8 +42,7 @@ def _note_interpret_fallback() -> None:
         "cpu): kernels will run in INTERPRET mode, which is orders of "
         "magnitude slower.  Use the compiled 'xla' fused backend "
         "(fused_backend='xla' / --fused-backend xla, the CPU auto-dispatch "
-        f"default), or silence this by setting {INTERPRET_ENV}=1 "
-        "explicitly.",
+        "default), or pass interpret=True explicitly.",
         RuntimeWarning, stacklevel=3,
     )
 
@@ -75,15 +57,6 @@ def resolve_interpret(interpret: bool | None = None, *,
     """
     if interpret is not None:
         return bool(interpret)
-    raw = os.environ.get(INTERPRET_ENV, "").strip().lower()
-    if raw in _TRUE:
-        return True
-    if raw in _FALSE:
-        return False
-    if raw not in ("", "auto"):
-        raise ValueError(
-            f"{INTERPRET_ENV}={raw!r} is not a recognized mode; use one of "
-            f"{_TRUE + _FALSE} or 'auto'")
     fallback = jax.default_backend() == "cpu"
     if fallback and not quiet:
         _note_interpret_fallback()

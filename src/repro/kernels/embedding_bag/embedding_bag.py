@@ -25,10 +25,9 @@ def _kernel(ids_ref, w_ref, table_ref, out_ref, *, b_blk, bag):
         k = i % bag
         idx = ids_ref[b, k]
         w = w_ref[b, k]
-        row = pl.load(table_ref, (pl.dslice(idx, 1), slice(None)))
-        cur = pl.load(out_ref, (pl.dslice(b, 1), slice(None)))
-        pl.store(out_ref, (pl.dslice(b, 1), slice(None)),
-                 cur + w * row.astype(jnp.float32))
+        row = table_ref[pl.ds(idx, 1), :]
+        out_ref[pl.ds(b, 1), :] = (out_ref[pl.ds(b, 1), :]
+                                   + w * row.astype(jnp.float32))
         return 0
 
     out_ref[...] = jnp.zeros_like(out_ref)

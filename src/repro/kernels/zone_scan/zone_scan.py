@@ -5,15 +5,16 @@ copy of the paper's Definition 2-5 transition semantics in Pallas land):
 
 **Dense per-zone kernel** (:func:`zone_scan_pallas`) — the seed layout.
 
-  Layout (all VMEM, lanes = candidates):
+  Layout (state in VMEM, lanes = candidates; inputs in SMEM):
 
     grid = (n_cand_blocks, n_edge_blocks)   # both sequential on TPU
-    scratch: candidate SoA for ONE candidate block —
+    scratch: candidate SoA for ONE candidate block (VMEM) —
         length/last_t/done/n_nodes  int32[1, C_BLK]
         nodes                       int32[K, C_BLK]   K = l_max + 1
         code                        int32[L, C_BLK]   L = n_limbs(l_max)
-    inputs per cell: one edge block (u, v, t, valid as int32[1, E_BLK])
-        plus the candidate block's seed times t_cand[1, C_BLK]
+    inputs per cell (SMEM, so the sweep reads each edge as scalars): one
+        edge block (u, v, t, valid as int32[1, E_BLK]) plus the candidate
+        block's seed times t_cand[1, C_BLK]
     outputs per candidate block: code int32[L, C_BLK], length int32[1, C_BLK]
 
   With the candidate axis OUTER, each candidate block streams the whole
@@ -32,8 +33,10 @@ to the flat span of the zones its lanes belong to.  Blocks may straddle
 zones and buckets: a per-slot ``zone_id`` gates every extension/seed/
 time-out to same-zone edges, so inert padding rows and foreign zones are
 masked rather than aligned away.  Candidate state lives in a pure
-``fori_loop`` carry (no cross-grid-step scratch), which keeps the kernel
-portable across the interpreter, Triton (GPU), and Mosaic.
+``fori_loop`` carry (no cross-grid-step scratch).  The per-block
+descriptors arrive by scalar prefetch; the flat stream stays in HBM and
+each live ``blk`` chunk is copied by DMA into SMEM, whose scalars the
+sweep reads one edge at a time.
 
 **Live-window block skipping** (beyond-paper, both kernels' key
 optimization): a (candidate-block x edge-chunk) cell is skipped when
@@ -46,8 +49,9 @@ optimization): a (candidate-block x edge-chunk) cell is skipped when
     more than ``l_max * delta`` (every candidate's lifetime is over —
     Lemma 4.1's span bound).  The dense kernel reads the chunk's first
     timestamp (edges are time-sorted within a zone); the fused kernel
-    reduces a masked min over the chunk, which stays conservative even
-    where the concatenated stream is not globally time-sorted.
+    compares a masked min over the chunk (computed per block before the
+    launch), which stays conservative even where the concatenated stream
+    is not globally time-sorted.
 
 Edges are time-sorted within each zone, so a candidate is live for
 ~``1/omega`` of its zone and skipping turns the dense O(E^2) sweep into
@@ -164,6 +168,13 @@ def _kernel(
     delta: int, l_max: int, c_blk: int, e_blk: int, n_e_blocks: int,
     with_ts: bool,
 ):
+    """One (candidate block, edge block) cell of the dense zone scan.
+
+    Every input block is a ``[1, blk]`` row in SMEM: the candidate
+    block's seed times ``t_cand`` and the edge block's ``u/v/t/valid``,
+    so the per-edge sweep reads scalars (Mosaic cannot index a dynamic
+    lane of a vector).
+    """
     if with_ts:
         (code_out_ref, len_out_ref, ts_out_ref,
          length_ref, last_t_ref, done_ref, nn_ref, nodes_ref, code_ref,
@@ -278,8 +289,11 @@ def zone_scan_pallas(
 
     n_c_blocks = e_pad // c_blk
     n_e_blocks = e_pad // e_blk
-    row = lambda x: x.reshape(1, e_pad)
-    u2, v2, t2, valid2 = row(u), row(v), row(t), row(valid_i)
+    # [n_blocks, 1, blk] rows: each [1, blk] SMEM block is one whole row,
+    # so its last two dims equal the array's, as Mosaic's tiling rule asks
+    rows = lambda x, b: x.reshape(e_pad // b, 1, b)
+    smem = lambda b, index_map: pl.BlockSpec(
+        (None, 1, b), index_map, memory_space=pltpu.SMEM)
 
     kernel = functools.partial(
         _kernel, delta=delta, l_max=l_max, c_blk=c_blk, e_blk=e_blk,
@@ -310,17 +324,17 @@ def zone_scan_pallas(
         kernel,
         grid=(n_c_blocks, n_e_blocks),
         in_specs=[
-            pl.BlockSpec((1, c_blk), lambda ci, ei: (0, ci)),   # t_cand
-            pl.BlockSpec((1, e_blk), lambda ci, ei: (0, ei)),   # u
-            pl.BlockSpec((1, e_blk), lambda ci, ei: (0, ei)),   # v
-            pl.BlockSpec((1, e_blk), lambda ci, ei: (0, ei)),   # t
-            pl.BlockSpec((1, e_blk), lambda ci, ei: (0, ei)),   # valid
+            smem(c_blk, lambda ci, ei: (ci, 0, 0)),   # t_cand
+            smem(e_blk, lambda ci, ei: (ei, 0, 0)),   # u
+            smem(e_blk, lambda ci, ei: (ei, 0, 0)),   # v
+            smem(e_blk, lambda ci, ei: (ei, 0, 0)),   # t
+            smem(e_blk, lambda ci, ei: (ei, 0, 0)),   # valid
         ],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch_shapes,
         interpret=interpret,
-    )(t2, u2, v2, t2, valid2)
+    )(rows(t, c_blk), *(rows(x, e_blk) for x in (u, v, t, valid_i)))
 
     code, length = outs[0], outs[1]
     if with_ts:
@@ -334,32 +348,37 @@ def zone_scan_pallas(
 
 
 def _fused_kernel(
-    lo_ref, hi_ref, u_ref, v_ref, t_ref, valid_ref, zid_ref,
-    lane_t_ref, lane_valid_ref, lane_zid_ref,
-    code_out_ref, len_out_ref, *maybe_ts_out_ref,
+    lo_ref, hi_ref, bmin_ref, bmax_ref,
+    u_hbm, v_hbm, t_hbm, valid_hbm, zid_hbm, lane_zid_ref,
+    code_out_ref, len_out_ref, *refs,
     delta: int, l_max: int, blk: int, with_ts: bool,
 ):
     """One candidate block of the concatenated flat slot stream.
 
-    Grid is 1-D over candidate blocks; the flat edge arrays arrive whole
-    (constant index map) and are chunk-loaded with dynamic slices, so the
-    host-planned sweep span ``[lo, hi)`` can differ per block — that is
-    what makes the ragged layout a *single* launch.  ``lo`` is the block's
+    Grid is 1-D over candidate blocks.  The per-block descriptors arrive
+    by scalar prefetch (SMEM): the host-planned sweep span ``[lo, hi)``,
+    each block's min valid edge time (``bmin``) and max valid seed time
+    (``bmax``).  The flat edge stream stays in HBM; each live ``blk``
+    chunk of the span is copied by DMA into SMEM, from which the sweep
+    reads one edge's scalars per step.  Because the span can differ per
+    block, the ragged layout is a *single* launch.  ``lo`` is the block's
     own base for live blocks (the sweep must pass over each lane's own
     slot to seed it) and equals ``hi`` for dead blocks (no valid lanes:
     zero chunks, outputs stay the zero init).  Candidate state is a pure
     ``fori_loop`` carry: no scratch persists across grid steps, so the
     kernel has no sequential-grid requirement.
     """
+    if with_ts:
+        ts_out_ref, *bufs = refs
+    else:
+        ts_out_ref, bufs = None, refs
     i = pl.program_id(0)
     base = i * blk
     limbs = code_out_ref.shape[0]
     k = l_max + 1
 
-    lo = lo_ref[0, 0]                       # blk-aligned sweep start
-    hi = hi_ref[0, 0]                       # blk-aligned sweep end
-    lane_t = lane_t_ref[...]                # [1, blk] seed times
-    lane_valid = lane_valid_ref[...] != 0
+    lo = lo_ref[i]                          # blk-aligned sweep start
+    hi = hi_ref[i]                          # blk-aligned sweep end
     lane_zid = lane_zid_ref[...]
     iota_lane = jax.lax.broadcasted_iota(jnp.int32, (1, blk), 1) + base
     iota_k = jax.lax.broadcasted_iota(jnp.int32, (k, blk), 0)
@@ -368,12 +387,12 @@ def _fused_kernel(
               if with_ts else None)
 
     # latest seed time among this block's real lanes: the Lemma-4.1 horizon
-    t_seed_max = jnp.max(jnp.where(lane_valid, lane_t, _I32_MIN))
+    horizon = bmax_ref[i] + l_max * delta
 
     state0 = (
         jnp.zeros((1, blk), jnp.int32),            # length
         jnp.zeros((1, blk), jnp.int32),            # last_t
-        jnp.zeros((1, blk), bool),                 # done
+        jnp.zeros((1, blk), jnp.int32),            # done (0/1)
         jnp.zeros((1, blk), jnp.int32),            # n_nodes
         jnp.full((k, blk), -1, jnp.int32),         # nodes
         jnp.zeros((limbs, blk), jnp.int32),        # code
@@ -383,32 +402,31 @@ def _fused_kernel(
 
     def chunk_body(ci, state):
         off = lo + ci * blk
-        cu = u_ref[0, pl.ds(off, blk)]
-        cv = v_ref[0, pl.ds(off, blk)]
-        ct = t_ref[0, pl.ds(off, blk)]
-        cvalid = valid_ref[0, pl.ds(off, blk)]
-        czid = zid_ref[0, pl.ds(off, blk)]
 
         # time skip: every valid edge in the chunk is beyond the horizon.
         # A masked min stays conservative on the (not globally time-sorted)
         # concatenated stream; the first chunk contains the lanes
-        # themselves, so min <= t_seed_max there and seeds are never lost.
-        min_t = jnp.min(jnp.where(cvalid != 0, ct, _I32_MAX))
-        live = min_t <= t_seed_max + l_max * delta
+        # themselves, so min <= horizon there and seeds are never lost.
+        live = bmin_ref[off // blk] <= horizon
 
         def sweep(st):
+            for src, buf in zip((u_hbm, v_hbm, t_hbm, valid_hbm, zid_hbm),
+                                bufs):
+                pltpu.sync_copy(src.at[off // blk], buf)
+            cu, cv, ct, cvalid, czid = bufs
+
             def body(j, s):
-                u = cu[j]
-                v = cv[j]
-                t = ct[j]
-                evalid = cvalid[j] != 0
-                return _edge_update(
-                    s, u=u, v=v, t=t,
-                    seed=(iota_lane == off + j) & evalid,
-                    gate=evalid & (czid[j] == lane_zid),
+                # the loop carries ``done`` as int32: Mosaic cannot carry
+                # a bool vector through a loop
+                evalid = cvalid[0, j] != 0
+                out = _edge_update(
+                    s[:2] + (s[2] != 0,) + s[3:], u=cu[0, j], v=cv[0, j],
+                    t=ct[0, j], seed=(iota_lane == off + j) & evalid,
+                    gate=evalid & (czid[0, j] == lane_zid),
                     delta=delta, l_max=l_max, iota_k=iota_k,
                     li_iota=li_iota, iota_l=iota_l,
                 )
+                return out[:2] + (out[2].astype(jnp.int32),) + out[3:]
             return jax.lax.fori_loop(0, blk, body, st)
 
         return jax.lax.cond(live, sweep, lambda s: s, state)
@@ -423,7 +441,7 @@ def _fused_kernel(
     code_out_ref[...] = state[5]
     len_out_ref[...] = state[0]
     if with_ts:
-        maybe_ts_out_ref[0][...] = state[6]
+        ts_out_ref[...] = state[6]
 
 
 def fused_zone_scan_flat(
@@ -463,15 +481,18 @@ def fused_zone_scan_flat(
             f"match {n_blocks} candidate blocks")
     limbs = encoding.n_limbs(l_max)
 
-    valid_i = valid.astype(jnp.int32)
-    row = lambda x: x.reshape(1, s_pad)
-    u2, v2, t2 = row(u), row(v), row(t)
-    valid2, zid2 = row(valid_i), row(zone_id)
-    lo2 = lo.reshape(1, n_blocks)
-    hi2 = hi.reshape(1, n_blocks)
+    stream = [jnp.asarray(x).astype(jnp.int32)
+              for x in (u, v, t, valid, zone_id)]
+    t_i, valid_i, zid_i = stream[2], stream[3], stream[4]
+    # per-block time bounds: min valid edge time (the chunk skip test) and
+    # max valid seed time (the block's horizon), one scalar each in SMEM
+    valid_t = lambda fill: jnp.where(valid_i != 0, t_i, fill).reshape(
+        n_blocks, blk)
+    bmin = jnp.min(valid_t(_I32_MAX), axis=1)
+    bmax = jnp.max(valid_t(_I32_MIN), axis=1)
 
-    whole = lambda shape: pl.BlockSpec(shape, lambda i: (0, 0))
-    per_block = lambda rows: pl.BlockSpec((rows, blk), lambda i: (0, i))
+    per_block = lambda rows: pl.BlockSpec((rows, blk), lambda i, *_: (0, i))
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
 
     kernel = functools.partial(
         _fused_kernel, delta=delta, l_max=l_max, blk=blk, with_ts=with_ts,
@@ -486,23 +507,18 @@ def fused_zone_scan_flat(
         out_shape.append(jax.ShapeDtypeStruct((l_max, s_pad), jnp.int32))
     outs = pl.pallas_call(
         kernel,
-        grid=(n_blocks,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, i)),     # lo descriptor
-            pl.BlockSpec((1, 1), lambda i: (0, i)),     # hi descriptor
-            whole((1, s_pad)),                          # u (full stream)
-            whole((1, s_pad)),                          # v
-            whole((1, s_pad)),                          # t
-            whole((1, s_pad)),                          # valid
-            whole((1, s_pad)),                          # zone_id
-            per_block(1),                               # lane seed times
-            per_block(1),                               # lane validity
-            per_block(1),                               # lane zone ids
-        ],
-        out_specs=out_specs,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,               # lo, hi, bmin, bmax
+            grid=(n_blocks,),
+            in_specs=[hbm] * 5 + [per_block(1)],  # stream; lane zone ids
+            out_specs=out_specs,
+            scratch_shapes=[pltpu.SMEM((1, blk), jnp.int32)] * 5,
+        ),
         out_shape=out_shape,
         interpret=interpret,
-    )(lo2, hi2, u2, v2, t2, valid2, zid2, t2, valid2, zid2)
+    )(jnp.asarray(lo, jnp.int32), jnp.asarray(hi, jnp.int32), bmin, bmax,
+      *(x.reshape(n_blocks, 1, blk) for x in stream),
+      zid_i.reshape(1, s_pad))
 
     code, length = outs[0], outs[1]
     if with_ts:

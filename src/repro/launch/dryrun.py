@@ -1,4 +1,8 @@
 import os
+# model-zoo tooling on 512 virtual CPU devices: pinned to the CPU backend
+# (here and in the --orchestrate children, which inherit the environment)
+# so it never takes a chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.

@@ -35,6 +35,7 @@ import repro.obs as obs_mod
 from repro.core import MiningConfig, PTMTEngine
 from repro.core.streaming import replay_stream
 from repro.data import synthetic_graphs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs.timing import latency_summary
 
 
@@ -121,6 +122,7 @@ def _run_stream(args, engine: PTMTEngine, graph):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     MiningConfig.add_cli_args(ap)
     ap.add_argument("--dataset", default="wikitalk-like",

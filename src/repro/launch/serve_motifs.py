@@ -53,6 +53,7 @@ import repro.obs as obs_mod
 from repro.core import MiningConfig, PTMTEngine
 from repro.core.temporal_graph import TemporalGraph
 from repro.data import synthetic_graphs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs.timing import percentile_ms
 from repro.serving.motif import MotifService, QueryRequest
 
@@ -469,6 +470,7 @@ def run_cluster_mode(args, config, obs, graph, streams, names) -> dict:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     MiningConfig.add_cli_args(ap)
     ap.add_argument("--dataset", default="sms-a-like",
