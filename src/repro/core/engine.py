@@ -35,6 +35,7 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import numpy as np
 
 from repro.obs import get_obs
@@ -58,8 +59,10 @@ class EngineStats:
     observability layer.  When the engine is built with a live
     :class:`repro.obs.Observability` bundle, every increment here is
     mirrored into the bundle's metrics registry
-    (``repro_mining_compile_cache_hits_total`` etc.), so Prometheus
-    exports and ``EngineStats`` always agree."""
+    (``repro_mining_compile_cache_hits_total`` etc.; ``launches`` as
+    ``repro_mining_launches_total`` by dispatch path, ``stream_launches``
+    under ``path="stream"``), so Prometheus exports and ``EngineStats``
+    always agree."""
 
     discover_calls: int = 0
     discover_many_calls: int = 0    # co-mined multi-config discover calls
@@ -73,6 +76,7 @@ class EngineStats:
     plan_cache_misses: int = 0      # discover calls that ran Algorithm 1
     zones_mined: int = 0
     launches: int = 0               # scan dispatches (fused layout run = 1)
+    stream_launches: int = 0        # scan dispatches of engine.stream() miners
     fused_runs: int = 0             # discover calls served by the fused path
     padding_ratio: float = 0.0      # last layout's padded-slot waste
     bucket_occupancy: dict = dataclasses.field(default_factory=dict)
@@ -224,32 +228,43 @@ class PTMTEngine:
         recurring bucket shapes dispatch to cached executables
         (``stats.compile_cache_hits``) and repeated calls on the same
         graph skip planning (``stats.plan_cache_hits``).
+
+        Spans: ``engine.mine`` around the whole call; inside it
+        ``engine.discover`` (planning, layout and the executor), then
+        ``engine.d2h`` (the count table's copy to the host) and
+        ``engine.decode`` (the table rendered into the result's dict).
         """
         self.stats.discover_calls += 1
-        with self.obs.tracer.span("engine.discover",
-                                  n_edges=graph.n_edges) as sp:
-            plan, layout = self._plan_and_layout(graph)
-            keys = self.executor.layout_execution_keys(layout)
-            counts, run_stats = self.executor.run_layout(
-                layout, allow_overflow=self.config.allow_overflow)
-            sp.set(n_zones=plan.n_zones, path=run_stats.get("path"))
-        if str(run_stats.get("path", "")).startswith("fused"):
-            # one launch, one executable: the whole layout resolves to a
-            # single fused execution key ("fused" or "fused_<backend>"
-            # when dispatch rerouted the kernel, e.g. "fused_xla" on CPU)
-            self._note_execution(keys[0], layout.n_zones)
-            self.stats.fused_runs += 1
-        else:
-            for key, bucket in zip(keys, layout.buckets):
-                self._note_execution(key, bucket.n_zones)
-        self.stats.launches += int(run_stats.get("launches", 0))
-        self._note_layout(layout)
-        return counts_to_result(
-            counts, n_zones=plan.n_zones, e_cap=layout.e_cap,
-            overflow=layout.overflow, delta=self.config.delta,
-            l_max=self.config.l_max,
-            layout={**layout.summary(), "execution": dict(run_stats)},
-        )
+        tracer = self.obs.tracer
+        with tracer.span("engine.mine", n_edges=graph.n_edges):
+            with tracer.span("engine.discover",
+                             n_edges=graph.n_edges) as sp:
+                plan, layout = self._plan_and_layout(graph)
+                keys = self.executor.layout_execution_keys(layout)
+                counts, run_stats = self.executor.run_layout(
+                    layout, allow_overflow=self.config.allow_overflow)
+                sp.set(n_zones=plan.n_zones, path=run_stats.get("path"))
+            if str(run_stats.get("path", "")).startswith("fused"):
+                # one launch, one executable: the whole layout resolves to
+                # a single fused execution key ("fused" or "fused_<backend>"
+                # when dispatch rerouted the kernel, e.g. "fused_xla" on CPU)
+                self._note_execution(keys[0], layout.n_zones)
+                self.stats.fused_runs += 1
+            else:
+                for key, bucket in zip(keys, layout.buckets):
+                    self._note_execution(key, bucket.n_zones)
+            self.stats.launches += int(run_stats.get("launches", 0))
+            self._note_layout(layout)
+            with tracer.span("engine.d2h", rows=int(counts.counts.shape[0])):
+                counts = jax.device_get(counts)
+            with tracer.span("engine.decode"):
+                return counts_to_result(
+                    counts, n_zones=plan.n_zones, e_cap=layout.e_cap,
+                    overflow=layout.overflow, delta=self.config.delta,
+                    l_max=self.config.l_max,
+                    layout={**layout.summary(),
+                            "execution": dict(run_stats)},
+                )
 
     # -- config-lattice co-mining --------------------------------------------
 
@@ -356,14 +371,15 @@ class PTMTEngine:
 
         Without overrides the miner shares this engine's executor (and so
         its warm jit state); with overrides a derived config (and executor)
-        is built for the miner alone.
+        is built for the miner alone.  Either way the miner counts its
+        scan launches into ``stats.stream_launches``.
         """
         self.stats.stream_sessions += 1
         if overrides:
             return StreamingMiner(config=self.config.with_updates(
-                **overrides), obs=self.obs)
+                **overrides), obs=self.obs, stats=self.stats)
         return StreamingMiner(config=self.config, executor=self.executor,
-                              obs=self.obs)
+                              obs=self.obs, stats=self.stats)
 
     # -- mesh path ----------------------------------------------------------
 

@@ -97,6 +97,14 @@ class MultiRunOutcome(NamedTuple):
 FUSED_MODES = ("auto", "on", "off")
 
 
+@functools.partial(jax.jit, static_argnames=("cap",))
+def _merge_part_jit(carry, part, *, cap):
+    """One cross-bucket ``merge_bounded`` step, named ``merge`` in the
+    program (a scope opened around an eager call would not reach it)."""
+    with jax.named_scope("merge"):
+        return aggregation.merge_bounded(carry, part, cap=cap)
+
+
 def merge_partial_counts(
     parts,
     *,
@@ -130,7 +138,7 @@ def merge_partial_counts(
             carry = aggregation.empty_counts(cap, limbs)
             spilled = jnp.zeros((), jnp.int32)
             for part in parts:
-                carry, spill = aggregation.merge_bounded(carry, part, cap=cap)
+                carry, spill = _merge_part_jit(carry, part, cap=cap)
                 spilled = spilled + spill
             n_spilled = int(spilled)
             if n_spilled == 0:
@@ -172,7 +180,8 @@ def _chunked_scan(scan, u, v, t, valid, *, delta, l_max, zone_chunk):
 
     def chunk_fn(args):
         cu, cv, ct, cvalid = args
-        res = scan(cu, cv, ct, cvalid, delta=delta, l_max=l_max)
+        with jax.named_scope("zone_scan"):
+            res = scan(cu, cv, ct, cvalid, delta=delta, l_max=l_max)
         return res.code, res.length
 
     z = u.shape[0]
@@ -221,10 +230,12 @@ def _hier_fold(scan, u, v, t, valid, signs, *, delta, l_max, zone_chunk,
     def body(carry, chunk):
         counts, spilled = carry
         cu, cv, ct, cvalid, csigns = chunk
-        res = scan(cu, cv, ct, cvalid, delta=delta, l_max=l_max)
-        part = aggregation.aggregate_zones(res.code, res.length, csigns)
-        merged, spill = aggregation.merge_bounded(counts, part,
-                                                 cap=merge_cap)
+        with jax.named_scope("zone_scan"):
+            res = scan(cu, cv, ct, cvalid, delta=delta, l_max=l_max)
+        with jax.named_scope("fold"):
+            part = aggregation.aggregate_zones(res.code, res.length, csigns)
+            merged, spill = aggregation.merge_bounded(counts, part,
+                                                      cap=merge_cap)
         return (merged, spilled + spill), None
 
     init = (aggregation.empty_counts(merge_cap, limbs), jnp.int32(0))
@@ -247,7 +258,8 @@ def _mine_jit(u, v, t, valid, signs, *, delta, l_max, scan, zone_chunk):
     codes, lengths = _chunked_scan(
         scan, u, v, t, valid, delta=delta, l_max=l_max, zone_chunk=zone_chunk
     )
-    return aggregation.aggregate_zones(codes, lengths, signs)
+    with jax.named_scope("fold"):
+        return aggregation.aggregate_zones(codes, lengths, signs)
 
 
 @functools.partial(
@@ -274,9 +286,12 @@ def _pipeline_step(carry, spilled, u, v, t, valid, signs, *, delta, l_max,
     place, so the resident aggregation state stays a single ``merge_cap``
     table no matter how many chunks stream through.
     """
-    res = scan(u, v, t, valid, delta=delta, l_max=l_max)
-    part = aggregation.aggregate_zones(res.code, res.length, signs)
-    merged, spill = aggregation.merge_bounded(carry, part, cap=merge_cap)
+    with jax.named_scope("zone_scan"):
+        res = scan(u, v, t, valid, delta=delta, l_max=l_max)
+    with jax.named_scope("fold"):
+        part = aggregation.aggregate_zones(res.code, res.length, signs)
+        merged, spill = aggregation.merge_bounded(carry, part,
+                                                  cap=merge_cap)
     return merged, spilled + spill
 
 
@@ -297,14 +312,11 @@ def _mine_fused_jit(u, v, t, valid, zone_id, sign, lo, hi, *, delta, l_max,
     device.  The [S, L] code block never round-trips to host.  ``scan``
     is a static arg, so the Pallas and XLA lowerings compile separately.
     """
-    code, length = scan(u, v, t, valid, zone_id, lo, hi,
-                        delta=delta, l_max=l_max, blk=blk)
+    with jax.named_scope("zone_scan"):
+        code, length = scan(u, v, t, valid, zone_id, lo, hi,
+                            delta=delta, l_max=l_max, blk=blk)
     s, limbs = code.shape
-    w = (length > 0).astype(jnp.int32) * sign
-    codes = jnp.where(w[:, None] != 0, code, 0)
     nchunk = s // fold_chunk
-    xs = (codes.reshape(nchunk, fold_chunk, limbs),
-          w.reshape(nchunk, fold_chunk))
 
     def body(carry, chunk):
         counts, spilled = carry
@@ -315,7 +327,12 @@ def _mine_fused_jit(u, v, t, valid, zone_id, sign, lo, hi, *, delta, l_max,
         return (merged, spilled + spill), None
 
     init = (aggregation.empty_counts(merge_cap, limbs), jnp.int32(0))
-    (counts, spilled), _ = jax.lax.scan(body, init, xs)
+    with jax.named_scope("fold"):
+        w = (length > 0).astype(jnp.int32) * sign
+        codes = jnp.where(w[:, None] != 0, code, 0)
+        xs = (codes.reshape(nchunk, fold_chunk, limbs),
+              w.reshape(nchunk, fold_chunk))
+        (counts, spilled), _ = jax.lax.scan(body, init, xs)
     return counts, spilled
 
 
@@ -324,8 +341,10 @@ def _mine_fused_jit(u, v, t, valid, zone_id, sign, lo, hi, *, delta, l_max,
 )
 def _merge_chunk_jit(carry, spilled, codes, lengths, signs, *, merge_cap):
     """Bounded merge of one host-scanned chunk (host-only backends)."""
-    part = aggregation.aggregate_zones(codes, lengths, signs)
-    merged, spill = aggregation.merge_bounded(carry, part, cap=merge_cap)
+    with jax.named_scope("fold"):
+        part = aggregation.aggregate_zones(codes, lengths, signs)
+        merged, spill = aggregation.merge_bounded(carry, part,
+                                                  cap=merge_cap)
     return merged, spilled + spill
 
 
@@ -375,17 +394,20 @@ def _mine_multi_jit(u, v, t, valid, signs, *, delta, l_max, scan, zone_chunk,
 
     def body(carry, chunk):
         cu, cv, ct, cvalid, csigns = chunk
-        res = scan(cu, cv, ct, cvalid, delta=delta, l_max=l_max,
-                   with_ts=True)
+        with jax.named_scope("zone_scan"):
+            res = scan(cu, cv, ct, cvalid, delta=delta, l_max=l_max,
+                       with_ts=True)
         new_carry = []
-        for (d_i, l_i), (counts, spilled), cap in zip(params, carry,
-                                                      merge_caps):
-            code_i, len_i = _derive_member(
-                res.code, res.length, res.ts,
-                d_i=d_i, l_i=l_i, delta=delta, l_max=l_max)
-            part = aggregation.aggregate_zones(code_i, len_i, csigns)
-            merged, spill = aggregation.merge_bounded(counts, part, cap=cap)
-            new_carry.append((merged, spilled + spill))
+        with jax.named_scope("fold"):
+            for (d_i, l_i), (counts, spilled), cap in zip(params, carry,
+                                                          merge_caps):
+                code_i, len_i = _derive_member(
+                    res.code, res.length, res.ts,
+                    d_i=d_i, l_i=l_i, delta=delta, l_max=l_max)
+                part = aggregation.aggregate_zones(code_i, len_i, csigns)
+                merged, spill = aggregation.merge_bounded(counts, part,
+                                                          cap=cap)
+                new_carry.append((merged, spilled + spill))
         return tuple(new_carry), None
 
     init = tuple(
@@ -410,8 +432,10 @@ def _mine_fused_multi_jit(u, v, t, valid, zone_id, sign, lo, hi, *, delta,
     through its own ``count_codes`` + ``merge_bounded`` fold inside the
     same executable.
     """
-    code, length, ts = scan(u, v, t, valid, zone_id, lo, hi,
-                            delta=delta, l_max=l_max, blk=blk, with_ts=True)
+    with jax.named_scope("zone_scan"):
+        code, length, ts = scan(u, v, t, valid, zone_id, lo, hi,
+                                delta=delta, l_max=l_max, blk=blk,
+                                with_ts=True)
     s, limbs = code.shape
     nchunk = s // fold_chunk
     xs = (code.reshape(nchunk, fold_chunk, limbs),
@@ -437,7 +461,8 @@ def _mine_fused_multi_jit(u, v, t, valid, zone_id, sign, lo, hi, *, delta,
     init = tuple(
         (aggregation.empty_counts(cap, limbs), jnp.int32(0))
         for cap in merge_caps)
-    out, _ = jax.lax.scan(body, init, xs)
+    with jax.named_scope("fold"):
+        out, _ = jax.lax.scan(body, init, xs)
     return out
 
 
@@ -447,10 +472,12 @@ def _mine_fused_multi_jit(u, v, t, valid, zone_id, sign, lo, hi, *, delta,
 def _derive_merge_chunk_jit(carry, spilled, codes, lengths, ts, signs, *,
                             d_i, l_i, delta, l_max, merge_cap):
     """One member config's bounded merge of a host-scanned chunk."""
-    code_i, len_i = _derive_member(codes, lengths, ts, d_i=d_i, l_i=l_i,
-                                   delta=delta, l_max=l_max)
-    part = aggregation.aggregate_zones(code_i, len_i, signs)
-    merged, spill = aggregation.merge_bounded(carry, part, cap=merge_cap)
+    with jax.named_scope("fold"):
+        code_i, len_i = _derive_member(codes, lengths, ts, d_i=d_i,
+                                       l_i=l_i, delta=delta, l_max=l_max)
+        part = aggregation.aggregate_zones(code_i, len_i, signs)
+        merged, spill = aggregation.merge_bounded(carry, part,
+                                                  cap=merge_cap)
     return merged, spilled + spill
 
 
@@ -689,7 +716,8 @@ class MiningExecutor:
             self.spec.scan, u, v, t, valid,
             delta=self.delta, l_max=self.l_max, zone_chunk=self.zone_chunk,
         )
-        return aggregation.aggregate_zones(codes, lengths, signs)
+        with jax.named_scope("fold"):
+            return aggregation.aggregate_zones(codes, lengths, signs)
 
     def scan_aggregate_partial(self, u, v, t, valid, signs):
         """Traceable scan+aggregate honoring the executor's ``agg`` mode.
@@ -963,15 +991,10 @@ class MiningExecutor:
             sp.sync(arrays)
         retries = 0
         while True:
-            # one span per launch attempt; the compile key changes when a
-            # spill retry doubles merge_cap (a genuine recompile), so the
-            # tracer's compile-vs-exec attribution stays honest
-            ck = ("fused", self.backend, fspec.name, fl.bounds, self.delta,
-                  self.l_max, fl.n_slots, blk, fold_chunk, merge_cap) \
-                if obs.enabled else None
+            # one span per launch attempt: a spill retry at a doubled
+            # merge_cap recompiles, and its jax.compile event lands here
             with obs.tracer.span("mine.fused", n_slots=fl.n_slots,
-                                 merge_cap=merge_cap, retry=retries,
-                                 compile_key=ck) as sp:
+                                 merge_cap=merge_cap, retry=retries) as sp:
                 counts, spilled = _mine_fused_jit(
                     *arrays, delta=self.delta, l_max=self.l_max,
                     scan=fspec.fused_scan, blk=blk,
@@ -1260,12 +1283,7 @@ class MiningExecutor:
         u, v, t, valid, signs = (np.asarray(x)
                                  for x in (u, v, t, valid, signs))
         z, e = u.shape
-        # compile key from the raw shape — execution_key replays the same
-        # pad/chunk resolution run below, so the tracer's compile-vs-exec
-        # attribution lines up with the engine's warm-call accounting
-        ck = self.execution_key(z, e) if self.obs.enabled else None
-        with self.obs.tracer.span("mine.launch", z=z, e=e, label=label,
-                                  compile_key=ck) as sp:
+        with self.obs.tracer.span("mine.launch", z=z, e=e, label=label) as sp:
             zc = self._zone_chunk_for(z, e)
             if zc and zc < z and z % zc != 0:
                 if self.pad_policy == "raise":

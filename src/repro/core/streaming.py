@@ -141,7 +141,10 @@ class StreamingMiner:
     internally), but never both.  ``executor=`` optionally shares an
     already-built :class:`MiningExecutor` (the
     :class:`repro.core.engine.PTMTEngine` path — one warm backend across
-    batch and stream modes); it must agree with the config.
+    batch and stream modes); it must agree with the config.  ``stats=``
+    is the :class:`~repro.core.engine.EngineStats` that counts this
+    miner's scan launches (``stream_launches``; ``engine.stream()`` passes
+    its own).
 
     Usage::
 
@@ -167,6 +170,7 @@ class StreamingMiner:
         merge_cap: int | None = None,
         memory_budget_mb: float | None = None,
         obs=None,
+        stats=None,
     ):
         legacy = {k: v for k, v in dict(
             delta=delta, l_max=l_max, omega=omega, e_cap=e_cap,
@@ -213,6 +217,9 @@ class StreamingMiner:
             executor.obs if executor is not None else get_obs(None))
         self.executor = executor if executor is not None \
             else MiningExecutor.from_config(config, obs=self.obs)
+        # the owning engine's EngineStats (engine.stream()), which counts
+        # this miner's scan launches; None for a standalone miner
+        self.stats = stats
 
         self._u = np.zeros(0, np.int32)     # sliding buffer: edges >= s
         self._v = np.zeros(0, np.int32)
@@ -245,6 +252,13 @@ class StreamingMiner:
 
     def _obs_labels(self) -> dict:
         return {"miner": self.obs_label} if self.obs_label else {}
+
+    def _count_launches(self, run_stats: dict) -> None:
+        n = int(run_stats.get("launches", 0))
+        if self.stats is not None:
+            self.stats.stream_launches += n
+        self.obs.metrics.counter("repro_mining_launches_total",
+                                 path="stream").inc(n)
 
     # -- stream state -------------------------------------------------------
 
@@ -360,6 +374,11 @@ class StreamingMiner:
         and multiplies the distinct jit shapes on the ingest hot path
         (measured ~1.6× slower warm, far worse cold).  The multi-zone
         tail mine is where the configured layout pays off.
+
+        Spans: ``stream.finalize`` holds ``stream.pair_layout`` (the
+        layout), ``stream.pair_mine`` (the executor run) and
+        ``stream.pair_merge`` (the counts to the host, decoded and merged
+        into the running totals).
         """
         hi = int(np.searchsorted(self._t, e, side="left"))
         b_lo = int(np.searchsorted(self._t, e - self.l_b, side="left"))
@@ -386,15 +405,20 @@ class StreamingMiner:
             t_end=np.asarray([e - t_base, e - t_base], np.int64),
             l_b=self.l_b,
         )
-        # cap at a power of two so jit shapes stabilize across pairs
-        with self.obs.tracer.span("stream.finalize", edges=g_cnt):
-            layout = tzp.build_zone_layout(
-                pair, plan, layout="dense",
-                e_cap=tzp.next_pow2(max(g_cnt, 8)),
-            )
-            counts = self.executor.run_layout(layout).counts
-            _merge_into(self._counts,
-                        transitions.device_counts_to_dict(counts))
+        tracer = self.obs.tracer
+        with tracer.span("stream.finalize", edges=g_cnt):
+            with tracer.span("stream.pair_layout"):
+                # cap at a power of two so jit shapes stabilize across pairs
+                layout = tzp.build_zone_layout(
+                    pair, plan, layout="dense",
+                    e_cap=tzp.next_pow2(max(g_cnt, 8)),
+                )
+            with tracer.span("stream.pair_mine"):
+                outcome = self.executor.run_layout(layout)
+            with tracer.span("stream.pair_merge"):
+                _merge_into(self._counts,
+                            transitions.device_counts_to_dict(outcome.counts))
+        self._count_launches(outcome.stats)
         self.n_zones_finalized += 2
 
     # -- results ------------------------------------------------------------
@@ -539,9 +563,10 @@ class StreamingMiner:
                 pad_edges_to=64,
             )
             sp.set(n_zones=plan.n_zones)
-            tail_counts = self.executor.run_layout(layout).counts
+            outcome = self.executor.run_layout(layout)
             self.last_tail_layout = layout.summary()
-        return (transitions.device_counts_to_dict(tail_counts),
+        self._count_launches(outcome.stats)
+        return (transitions.device_counts_to_dict(outcome.counts),
                 plan.n_zones, layout.e_cap)
 
     # -- checkpoint state round-trip -----------------------------------------

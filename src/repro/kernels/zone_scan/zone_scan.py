@@ -334,6 +334,7 @@ def zone_scan_pallas(
         out_shape=out_shape,
         scratch_shapes=scratch_shapes,
         interpret=interpret,
+        name="zone_scan_dense",
     )(rows(t, c_blk), *(rows(x, e_blk) for x in (u, v, t, valid_i)))
 
     code, length = outs[0], outs[1]
@@ -516,6 +517,7 @@ def fused_zone_scan_flat(
         ),
         out_shape=out_shape,
         interpret=interpret,
+        name="zone_scan_fused",
     )(jnp.asarray(lo, jnp.int32), jnp.asarray(hi, jnp.int32), bmin, bmax,
       *(x.reshape(n_blocks, 1, blk) for x in stream),
       zid_i.reshape(1, s_pad))

@@ -5,7 +5,9 @@ all its telemetry through ONE :class:`Observability` bundle — a
 :class:`~repro.obs.metrics.MetricsRegistry` (counters / gauges /
 histograms, exportable as JSON and Prometheus text) plus a
 :class:`~repro.obs.tracing.Tracer` (nested spans with device-accurate
-timing and compile-vs-exec attribution, exportable as Chrome-trace JSON).
+timing, each recording its parent and root span; the backend compilations
+that happen under them; all mirrored into a ``jax.profiler`` trace as
+``span:<name>`` annotations and exportable as Chrome-trace JSON).
 
 Opt-in by construction: the default everywhere is :data:`NULL_OBS`, whose
 registry and tracer are shared no-op singletons, so instrumented code pays
@@ -73,8 +75,10 @@ class Observability:
 
     @classmethod
     def enabled_bundle(cls) -> "Observability":
-        """A fresh live registry + tracer."""
-        return cls(metrics=MetricsRegistry(), tracer=Tracer())
+        """A fresh live registry + tracer (which counts compilations into
+        the registry)."""
+        registry = MetricsRegistry()
+        return cls(metrics=registry, tracer=Tracer(metrics=registry))
 
 
 def enabled() -> Observability:

@@ -201,3 +201,66 @@ def test_sharded_caches_mesh_step_and_matches_single_device():
     with warnings.catch_warnings():
         warnings.simplefilter("ignore", DeprecationWarning)
         assert a.counts == PTMTEngine(CFG).discover(g).counts
+
+
+# -- spans and counters ------------------------------------------------------
+
+def test_spans_of_one_mine_share_the_root_engine_mine():
+    import repro.obs as obs_mod
+
+    obs = obs_mod.enabled()
+    engine = PTMTEngine(CFG, obs=obs)
+    g = _graph()
+    res = engine.discover(g)
+    engine.discover(g)
+    events = obs.tracer.events()
+    mines = [e for e in events if e["name"] == "engine.mine"]
+    assert len(mines) == 2
+    first = mines[0]["args"]["span_id"]
+    mine_events = [e for e in events if e["args"]["root_id"] == first]
+    names = {e["name"] for e in mine_events}
+    assert {"engine.discover", "engine.d2h", "engine.decode",
+            "mine.launch"} <= names
+    by_name = {e["name"]: e for e in mine_events}
+    discover = by_name["engine.discover"]
+    d2h, decode = by_name["engine.d2h"], by_name["engine.decode"]
+    # the decode spans follow engine.discover, outside it, in order
+    for span in (d2h, decode):
+        assert span["args"]["parent_id"] == first
+        assert span["ts"] >= discover["ts"] + discover["dur"]
+    assert decode["ts"] >= d2h["ts"] + d2h["dur"]
+    assert res.counts == engine.discover(g).counts
+
+
+def test_stream_pairs_are_split_and_their_launches_counted():
+    import repro.obs as obs_mod
+
+    obs = obs_mod.enabled()
+    engine = PTMTEngine(CFG, obs=obs)
+    g = _graph()
+    miner = engine.stream()
+    for i in range(0, g.n_edges, 64):
+        miner.ingest(g.u[i:i + 64], g.v[i:i + 64], g.t[i:i + 64])
+    events = obs.tracer.events()
+    ids = {e["args"]["span_id"]: e for e in events}
+    finalize = [e for e in events if e["name"] == "stream.finalize"]
+    assert finalize
+    for name in ("stream.pair_layout", "stream.pair_mine",
+                 "stream.pair_merge"):
+        spans = [e for e in events if e["name"] == name]
+        assert len(spans) == len(finalize)
+        for e in spans:
+            assert ids[e["args"]["parent_id"]]["name"] == "stream.finalize"
+            assert ids[e["args"]["root_id"]]["name"] == "stream.ingest"
+    pairs = engine.stats.stream_launches
+    assert pairs == len(finalize)        # one dense launch per pair
+    miner.snapshot(final=True)
+    assert engine.stats.stream_launches > pairs         # the tail mine
+    assert engine.stats.launches == 0                   # no batch mine
+    assert obs.metrics.counter("repro_mining_launches_total",
+                               path="stream").value \
+        == engine.stats.stream_launches
+    # a standalone miner counts into no engine
+    solo = StreamingMiner(config=CFG)
+    solo.ingest(g.u, g.v, g.t)
+    assert solo.stats is None

@@ -238,3 +238,66 @@ def test_budget_derived_zone_chunk_is_exact():
 def test_executor_rejects_unknown_agg_mode():
     with pytest.raises(ValueError, match="agg mode"):
         MiningExecutor(delta=5, l_max=3, agg="no-such-mode")
+
+
+# ---------------------------------------------------------------------------
+# Names in the device trace.
+# ---------------------------------------------------------------------------
+
+
+def _scopes(lowered_text):
+    """The named scopes in a lowered program's op locations."""
+    import re
+
+    names = re.findall(r'loc\("([^"]*)"', lowered_text)
+    return {part for name in names for part in name.split("/")}
+
+
+def _small_zone_arrays(z=4, e=32):
+    rng = np.random.default_rng(0)
+    u = rng.integers(0, 6, (z, e)).astype(np.int32)
+    v = rng.integers(0, 6, (z, e)).astype(np.int32)
+    t = np.sort(rng.integers(0, 500, (z, e)), axis=1).astype(np.int32)
+    valid = np.ones((z, e), bool)
+    signs = np.array([1, -1] * (z // 2), np.int32)
+    return u, v, t, valid, signs
+
+
+@pytest.mark.parametrize("program", ["hier", "legacy"])
+def test_mining_programs_name_the_scan_and_the_fold(program):
+    from repro.core import executor as ex_mod
+
+    arrays = _small_zone_arrays()
+    scan = get_backend("ref").scan
+    if program == "hier":
+        lowered = ex_mod._mine_jit_hier.lower(
+            *arrays, delta=60, l_max=3, scan=scan, zone_chunk=2,
+            merge_cap=64)
+    else:
+        lowered = ex_mod._mine_jit.lower(
+            *arrays, delta=60, l_max=3, scan=scan, zone_chunk=2)
+    scopes = _scopes(lowered.as_text(debug_info=True))
+    assert {"zone_scan", "fold"} <= scopes
+    assert "merge" not in scopes
+
+
+def test_cross_bucket_merge_is_named_merge():
+    from repro.core import aggregation
+    from repro.core import executor as ex_mod
+
+    a = aggregation.empty_counts(16, 2)
+    text = ex_mod._merge_part_jit.lower(a, a, cap=16).as_text(
+        debug_info=True)
+    scopes = _scopes(text)
+    assert "merge" in scopes and "fold" not in scopes
+    # and merge_partial_counts runs through it, with an exact result
+    g = random_graph(3, 300, 20, 2_000)
+    plan = tzp.plan_zones(g, delta=60, l_max=3, omega=2)
+    layout = tzp.build_zone_layout(g, plan, layout="bucketed")
+    ex = MiningExecutor(delta=60, l_max=3)
+    parts = [ex.run_arrays(b.u, b.v, b.t, b.valid, b.sign)
+             for b in layout.buckets]
+    assert len(parts) > 1
+    merged = ex_mod.merge_partial_counts(parts)
+    assert _counts_dict(merged) == dict(
+        oracle.count_codes(g.u, g.v, g.t, 60, 3))

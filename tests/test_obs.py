@@ -4,8 +4,10 @@ The guarantees the rest of the stack leans on:
 
 * histograms are **exact** below the sample bound (nearest-rank, matching
   numpy's ``inverted_cdf``) and degrade to bucket interpolation above it;
-* spans nest, time-contain their children, attribute first-call compile
-  vs steady-state exec per compile key, and survive exceptions;
+* spans nest, time-contain their children, record their parent and root
+  span (per thread), record the compilations that happen under them,
+  appear in a ``jax.profiler`` trace under a prefixed name, and survive
+  exceptions;
 * the Chrome-trace and Prometheus exports are schema-valid and the JSON
   snapshot round-trips through ``json``;
 * the disabled mode (``NULL_OBS``) is shared no-op singletons — no state,
@@ -166,19 +168,176 @@ def test_span_nesting_and_containment():
     assert tr.span_names() == {"inner", "outer"}
 
 
-def test_compile_exec_attribution():
+def _by_name(tr):
+    return {e["name"]: e for e in tr.events()}
+
+
+def test_span_parent_and_root_ids():
     tr = Tracer()
-    key = ("fused", "pallas", 90, 5)
-    for _ in range(3):
-        with tr.span("mine.fused", compile_key=key):
+    with tr.span("root"):
+        with tr.span("mid"):
+            with tr.span("leaf"):
+                pass
+        with tr.span("sibling"):
             pass
-    phases = [e["args"]["phase"] for e in tr.events()]
-    assert phases == ["compile", "exec", "exec"]
-    att = tr.attribution()[repr(key)]
-    assert att["span"] == "mine.fused"
-    assert att["exec_calls"] == 2
-    assert att["compile_ms"] >= 0.0
-    assert att["exec_ms_min"] is not None
+    with tr.span("next_root"):
+        pass
+    ev = {n: e["args"] for n, e in _by_name(tr).items()}
+    root = ev["root"]["span_id"]
+    assert ev["root"]["parent_id"] is None and ev["root"]["root_id"] == root
+    assert ev["mid"]["parent_id"] == root
+    assert ev["leaf"]["parent_id"] == ev["mid"]["span_id"]
+    assert ev["sibling"]["parent_id"] == root
+    assert {ev[n]["root_id"] for n in ("mid", "leaf", "sibling")} == {root}
+    nxt = ev["next_root"]
+    assert nxt["parent_id"] is None and nxt["root_id"] == nxt["span_id"]
+    ids = [a["span_id"] for a in ev.values()]
+    assert len(set(ids)) == len(ids)
+
+
+def test_span_ids_stay_on_their_thread():
+    """A span opened on another thread while one is open here is a root
+    of its own, and its children point at it, not at this thread's span."""
+    tr = Tracer()
+    gate = threading.Barrier(3)
+
+    def worker(i):
+        gate.wait()
+        with tr.span(f"w{i}"):
+            with tr.span(f"w{i}.child"):
+                gate.wait()
+
+    with tr.span("main"):
+        threads = [threading.Thread(target=worker, args=(i,))
+                   for i in range(2)]
+        for t in threads:
+            t.start()
+        gate.wait()              # both workers are inside their spans now
+        gate.wait()
+        for t in threads:
+            t.join()
+    ev = {n: e["args"] for n, e in _by_name(tr).items()}
+    for i in range(2):
+        top, child = ev[f"w{i}"], ev[f"w{i}.child"]
+        assert top["parent_id"] is None
+        assert top["root_id"] == top["span_id"]
+        assert child["parent_id"] == top["span_id"]
+        assert child["root_id"] == top["span_id"]
+    assert ev["main"]["parent_id"] is None
+
+
+def test_compile_recorded_under_the_span_that_compiled():
+    import jax
+    import jax.numpy as jnp
+
+    reg = MetricsRegistry()
+    tr = Tracer(metrics=reg)
+
+    def fresh_program(x):
+        return jnp.cumsum(x * 3) - 1
+
+    f = jax.jit(fresh_program)
+    with tr.span("outer"):
+        with tr.span("step") as sp:
+            sp.sync(f(jnp.arange(13.0)))
+    with tr.span("again"):
+        f(jnp.arange(13.0)).block_until_ready()      # cached: no compile
+    compiles = [e for e in tr.events() if e["name"] == "jax.compile"]
+    mine = [e for e in compiles if "fresh_program" in e["args"]["fun_name"]]
+    assert len(mine) == 1
+    ev = _by_name(tr)
+    args = mine[0]["args"]
+    assert args["span"] == "step"
+    assert args["parent_id"] == ev["step"]["args"]["span_id"]
+    assert args["root_id"] == ev["outer"]["args"]["span_id"]
+    assert mine[0]["dur"] > 0
+    step = ev["step"]
+    assert step["ts"] <= mine[0]["ts"]
+    assert mine[0]["ts"] + mine[0]["dur"] <= step["ts"] + step["dur"]
+    assert all(e["args"]["span"] != "again" for e in compiles)
+    counter = reg.counter("repro_jax_compiles_total", span="step")
+    assert counter.value == sum(e["args"]["span"] == "step"
+                                for e in compiles) >= 1
+    # outside every span nothing is recorded
+    n = len(tr.events())
+    jax.jit(lambda x: x - 7)(jnp.ones(5)).block_until_ready()
+    assert len(tr.events()) == n
+
+
+def test_compiles_on_threads_land_under_their_own_spans():
+    import sys
+
+    import jax
+    import jax.numpy as jnp
+
+    tr = Tracer()
+    n = 6
+    gate = threading.Barrier(n)
+    errors = []
+
+    def worker(i):
+        try:
+            f = jax.jit(lambda x: jnp.sin(x) * (i + 2))
+            gate.wait(timeout=30)
+            with tr.span(f"t{i}"):
+                f(jnp.arange(float(i + 3))).block_until_ready()
+        except Exception as e:                    # reported below
+            errors.append(e)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=worker, args=(i,))
+                   for i in range(n)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(old)
+    assert not errors and not any(t.is_alive() for t in threads)
+    spans = {e["args"]["span_id"]: e for e in tr.events()
+             if e["name"] != "jax.compile"}
+    compiles = [e for e in tr.events() if e["name"] == "jax.compile"]
+    assert {spans[e["args"]["parent_id"]]["name"] for e in compiles} \
+        == {f"t{i}" for i in range(n)}
+    for e in compiles:
+        parent = spans[e["args"]["parent_id"]]
+        assert e["tid"] == parent["tid"]
+        assert e["args"]["span"] == parent["name"]
+
+
+def test_one_compile_listener_for_every_tracer():
+    from jax._src import monitoring
+
+    from repro.obs import tracing
+
+    tracers = [Tracer() for _ in range(3)]
+    listeners = [cb for cb in monitoring._event_duration_secs_listeners
+                 if cb is tracing._on_duration_event]
+    assert len(listeners) == 1
+    assert all(t in tracing._live_tracers for t in tracers)
+
+
+def test_spans_appear_in_a_profiler_trace_once(tmp_path):
+    import jax
+
+    tr = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("bench.window"):
+            with tr.span("engine.decode"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    path, = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    profile = jax.profiler.ProfileData.from_file(str(path))
+    host = [ev.name for plane in profile.planes if plane.name == "/host:CPU"
+            for line in plane.lines for ev in line.events]
+    assert host.count("span:engine.decode") == 1
+    assert host.count("span:bench.window") == 1
+    # the bare names stay free for the caller's own annotations
+    assert "engine.decode" not in host and "bench.window" not in host
 
 
 def test_span_error_and_set_and_sync():
@@ -204,7 +363,7 @@ def test_tracer_bounded_buffer():
 
 def test_chrome_trace_schema(tmp_path):
     tr = Tracer()
-    with tr.span("a", compile_key=("k",)):
+    with tr.span("a"):
         pass
     path = tmp_path / "trace.json"
     tr.write(str(path))
@@ -217,7 +376,8 @@ def test_chrome_trace_schema(tmp_path):
         assert isinstance(e["ts"], (int, float))
         assert isinstance(e["dur"], (int, float))
         assert e["pid"] and e["tid"]
-    assert repr(("k",)) in doc["otherData"]["attribution"]
+        assert {"span_id", "parent_id", "root_id"} <= set(e["args"])
+    assert doc["otherData"] == {"dropped_events": 0}
 
 
 def test_tracer_threads_keep_local_nesting():
