@@ -112,6 +112,45 @@ def decode_code_np(limbs) -> str:
     return "".join(out)
 
 
+#: ASCII of each 4-bit digit's label character; digit 0 (padding) -> NUL
+_LABEL_ASCII = np.frombuffer(b"\x000123456789abcde", np.uint8)
+
+
+def label_bytes_np(codes) -> np.ndarray:
+    """Limb codes ``[N, L]`` -> label strings as a fixed-width bytes array.
+
+    Row ``i`` holds ``decode_code_np(codes[i])`` in ASCII, NUL-padded to
+    ``7 * L`` bytes (NumPy drops the padding when it reads an item).  For
+    valid codes bytes order equals limb order: ``"0"``-``"9"`` sort below
+    ``"a"``-``"e"`` and a prefix below its extensions.
+    """
+    codes = np.asarray(codes)
+    n, limbs = codes.shape
+    shifts = np.arange(DIGIT_BITS * (DIGITS_PER_LIMB - 1), -1, -DIGIT_BITS,
+                       dtype=codes.dtype)
+    digits = ((codes[..., None] >> shifts) & 0xF).reshape(
+        n, limbs * DIGITS_PER_LIMB)
+    pad = digits == 0
+    if np.any(pad[:, :-1] & ~pad[:, 1:]):
+        # decode_code_np skips a zero digit inside a code: move each row's
+        # zeros to its end, keeping the order of the rest
+        digits = np.take_along_axis(
+            digits, np.argsort(pad, axis=1, kind="stable"), axis=1)
+    return _LABEL_ASCII[digits].view(f"S{limbs * DIGITS_PER_LIMB}").reshape(n)
+
+
+def label_strings_np(labels: np.ndarray) -> list[str]:
+    """A :func:`label_bytes_np` array -> its ``str`` items, in one decode."""
+    if labels.size == 0:
+        return []
+    return b"\n".join(labels.tolist()).decode("ascii").split("\n")
+
+
+def decode_codes_np(codes) -> list[str]:
+    """Vector form of :func:`decode_code_np` over the rows of ``[N, L]``."""
+    return label_strings_np(label_bytes_np(codes))
+
+
 def code_length_np(limbs) -> int:
     """Number of edges encoded in a limb code."""
     return len(decode_code_np(limbs)) // 2

@@ -232,7 +232,8 @@ class PTMTEngine:
         Spans: ``engine.mine`` around the whole call; inside it
         ``engine.discover`` (planning, layout and the executor), then
         ``engine.d2h`` (the count table's copy to the host) and
-        ``engine.decode`` (the table rendered into the result's dict).
+        ``engine.decode`` (the table rendered into the result's dict; its
+        ``codes`` is the number of live codes decoded).
         """
         self.stats.discover_calls += 1
         tracer = self.obs.tracer
@@ -257,14 +258,16 @@ class PTMTEngine:
             self._note_layout(layout)
             with tracer.span("engine.d2h", rows=int(counts.counts.shape[0])):
                 counts = jax.device_get(counts)
-            with tracer.span("engine.decode"):
-                return counts_to_result(
+            with tracer.span("engine.decode") as sp:
+                result = counts_to_result(
                     counts, n_zones=plan.n_zones, e_cap=layout.e_cap,
                     overflow=layout.overflow, delta=self.config.delta,
                     l_max=self.config.l_max,
                     layout={**layout.summary(),
                             "execution": dict(run_stats)},
                 )
+                sp.set(codes=len(result.counts))
+            return result
 
     # -- config-lattice co-mining --------------------------------------------
 

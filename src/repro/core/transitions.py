@@ -12,6 +12,7 @@ from __future__ import annotations
 import dataclasses
 from collections import defaultdict
 
+import jax
 import numpy as np
 
 from . import encoding
@@ -84,25 +85,35 @@ class TransitionTree:
 
 def counts_to_dict(codes: np.ndarray, counts: np.ndarray,
                    mask: np.ndarray | None = None) -> dict[str, int]:
-    """Device count arrays -> {code string: count}, dropping zeros."""
-    out: dict[str, int] = defaultdict(int)
-    codes = np.asarray(codes)
+    """Device count arrays -> {code string: count}, dropping zeros.
+
+    Rows where ``mask`` holds and the count is non-zero are decoded in one
+    vector pass; counts of equal strings are summed and zero totals
+    dropped.  A device table is sorted and unique, so its labels are
+    strictly increasing and go straight into the dict; any other table is
+    folded first.
+    """
     counts = np.asarray(counts)
-    if mask is None:
-        mask = np.ones(counts.shape, bool)
-    for row, cnt in zip(codes[np.asarray(mask)], counts[np.asarray(mask)]):
-        if cnt == 0:
-            continue
-        out[encoding.decode_code_np(row)] += int(cnt)
-    return {k: v for k, v in out.items() if v != 0}
+    live = counts != 0
+    if mask is not None:
+        live &= np.asarray(mask, bool)
+    labels = encoding.label_bytes_np(np.asarray(codes)[live])
+    counts = counts[live].astype(np.int64)
+    if labels.size > 1 and not np.all(labels[1:] > labels[:-1]):
+        labels, inverse = np.unique(labels, return_inverse=True)
+        totals = np.zeros(labels.size, np.int64)
+        np.add.at(totals, inverse, counts)
+        labels, counts = labels[totals != 0], totals[totals != 0]
+    return dict(zip(encoding.label_strings_np(labels), counts.tolist()))
 
 
 def device_counts_to_dict(counts) -> dict[str, int]:
-    """:class:`~repro.core.aggregation.CodeCounts` -> {code string: count}."""
-    return counts_to_dict(
-        np.asarray(counts.codes), np.asarray(counts.counts),
-        np.asarray(counts.unique_mask),
-    )
+    """:class:`~repro.core.aggregation.CodeCounts` -> {code string: count}.
+
+    One ``jax.device_get`` copies the three arrays to the host together.
+    """
+    host = jax.device_get(counts)
+    return counts_to_dict(host.codes, host.counts, host.unique_mask)
 
 
 def build_tree(final_counts: dict[str, int]) -> TransitionTree:

@@ -96,3 +96,58 @@ def test_roundtrip_random(l_max, data):
     )
     code = encoding.encode_digits_np(digits, l_max)
     assert [int(c, 16) + 1 for c in encoding.decode_code_np(code)] == digits
+
+
+# -- vector decode -----------------------------------------------------------
+
+_INT32 = st.integers(-2**31, 2**31 - 1)
+
+
+def _pack(digits):
+    """7 digits (each 0..15) -> one int32 limb, as the device packs them."""
+    limb = 0
+    for d in digits:
+        limb = (limb << encoding.DIGIT_BITS) | d
+    return limb
+
+
+def _limb_strategy():
+    """Limbs whose digits are mostly label digits, with zeros inside and
+    0xF digits; plus raw int32 limbs (negative ones included)."""
+    digit = st.one_of(st.integers(1, 15), st.just(0), st.just(15))
+    packed = st.lists(digit, min_size=encoding.DIGITS_PER_LIMB,
+                      max_size=encoding.DIGITS_PER_LIMB).map(_pack)
+    return st.one_of(packed, st.just(0), _INT32)
+
+
+@pytest.mark.parametrize("l_max", [1, 3, 6, 7, 12, 14])
+@given(data=st.data())
+def test_decode_codes_np_equals_the_row_decode(l_max, data):
+    limbs = encoding.n_limbs(l_max)
+    rows = data.draw(st.lists(
+        st.one_of(st.just([0] * limbs),
+                  st.lists(_limb_strategy(), min_size=limbs,
+                           max_size=limbs)),
+        max_size=12))
+    codes = np.asarray(rows, np.int32).reshape(len(rows), limbs)
+    got = encoding.decode_codes_np(codes)
+    assert got == [encoding.decode_code_np(r) for r in codes]
+    assert all(type(s) is str for s in got)
+
+
+@pytest.mark.parametrize("l_max", [1, 3, 6, 7, 12, 14])
+def test_decode_codes_np_named_cases(l_max):
+    limbs = encoding.n_limbs(l_max)
+    valid = encoding.encode_label_string_np("0" + "e" * (2 * l_max - 1),
+                                            l_max)
+    interior_zero = np.zeros(limbs, np.int32)
+    interior_zero[0] = _pack([1, 0, 2, 0, 0, 15, 3])
+    negative = np.full(limbs, -1, np.int32)      # every digit 0xF
+    negative[-1] = -2**31 + 0xF                  # six zeros, then 0xF
+    codes = np.stack([np.zeros(limbs, np.int32), valid, interior_zero,
+                      negative])
+    expect = [encoding.decode_code_np(r) for r in codes]
+    assert expect[0] == "" and expect[2] == "01e2"
+    assert "e" in expect[1] and "e" in expect[3]
+    assert encoding.decode_codes_np(codes) == expect
+    assert encoding.decode_codes_np(np.zeros((0, limbs), np.int32)) == []
