@@ -61,8 +61,9 @@ class EngineStats:
     mirrored into the bundle's metrics registry
     (``repro_mining_compile_cache_hits_total`` etc.; ``launches`` as
     ``repro_mining_launches_total`` by dispatch path, ``stream_launches``
-    under ``path="stream"``), so Prometheus exports and ``EngineStats``
-    always agree."""
+    under ``path="stream"``, ``sweep_slots`` as
+    ``repro_mining_sweep_slots_total`` by dispatch path), so Prometheus
+    exports and ``EngineStats`` always agree."""
 
     discover_calls: int = 0
     discover_many_calls: int = 0    # co-mined multi-config discover calls
@@ -77,6 +78,7 @@ class EngineStats:
     zones_mined: int = 0
     launches: int = 0               # scan dispatches (fused layout run = 1)
     stream_launches: int = 0        # scan dispatches of engine.stream() miners
+    sweep_slots: int = 0            # candidate-steps of the launched scans
     fused_runs: int = 0             # discover calls served by the fused path
     padding_ratio: float = 0.0      # last layout's padded-slot waste
     bucket_occupancy: dict = dataclasses.field(default_factory=dict)
@@ -255,6 +257,7 @@ class PTMTEngine:
                 for key, bucket in zip(keys, layout.buckets):
                     self._note_execution(key, bucket.n_zones)
             self.stats.launches += int(run_stats.get("launches", 0))
+            self.stats.sweep_slots += int(run_stats.get("sweep_slots", 0))
             self._note_layout(layout)
             with tracer.span("engine.d2h", rows=int(counts.counts.shape[0])):
                 counts = jax.device_get(counts)
@@ -336,6 +339,7 @@ class PTMTEngine:
             for key, bucket in zip(keys, layout.buckets):
                 self._note_execution(key, bucket.n_zones)
         self.stats.launches += int(run_stats.get("launches", 0))
+        self.stats.sweep_slots += int(run_stats.get("sweep_slots", 0))
         self._note_layout(layout)
         layout_summary = {**layout.summary(), "execution": dict(run_stats)}
         for member, idx, counts in zip(lat.members, lat.indices,

@@ -198,6 +198,14 @@ def _chunked_scan(scan, u, v, t, valid, *, delta, l_max, zone_chunk):
     return codes, lengths
 
 
+def _padded_zones(z: int, zone_chunk: int) -> int:
+    """Zone rows after padding ``z`` up to a multiple of ``zone_chunk``
+    (pad_policy 'pad'); ``z`` itself when unchunked."""
+    if zone_chunk and zone_chunk < z and z % zone_chunk:
+        return z + zone_chunk - z % zone_chunk
+    return z
+
+
 def _n_chunks(z: int, zone_chunk: int) -> int:
     if z % zone_chunk != 0:
         raise ZoneChunkError(
@@ -639,13 +647,19 @@ class MiningExecutor:
         a doubled cap without changing the key — rare, and the retry warns.
         """
         zc = self._zone_chunk_for(z, e)
-        if zc and zc < z and z % zc != 0:
-            z += zc - z % zc
+        z = _padded_zones(z, zc)
         mode = self._agg_mode_for(zc, z)
         merge_cap = (self._merge_cap_for(zc, z, e)
                      if mode != "legacy" else 0)
         return (self.backend, self.delta, self.l_max, z, e, zc, mode,
                 merge_cap)
+
+    def bucket_sweep_slots(self, layout: ZoneBatchLayout) -> int:
+        """Candidate-steps the per-bucket Phase-1 scans visit: each padded
+        zone row of a ``[z, e]`` bucket sweeps ``e`` steps over ``e``
+        slots, so ``sum(z_padded * e * e)``, from shapes alone."""
+        return sum(_padded_zones(b.n_zones, self._zone_chunk_for(
+            b.n_zones, b.e_cap)) * b.e_cap ** 2 for b in layout.buckets)
 
     # -- capacity resolution ------------------------------------------------
 
@@ -894,10 +908,14 @@ class MiningExecutor:
                 "path": "per-bucket",
                 "launches": len(layout.buckets),
                 "spill_retries": 0,
+                "sweep_slots": self.bucket_sweep_slots(layout),
             }
             self.obs.metrics.counter(
                 "repro_mining_launches_total",
                 path="per-bucket").inc(len(layout.buckets))
+            self.obs.metrics.counter(
+                "repro_mining_sweep_slots_total",
+                path="per-bucket").inc(stats["sweep_slots"])
             counts = merge_partial_counts(parts, merge_cap=self.merge_cap,
                                           warn_label="zone-layout bucket",
                                           obs=self.obs)
@@ -1018,6 +1036,8 @@ class MiningExecutor:
                 }
                 obs.metrics.counter("repro_mining_launches_total",
                                     path=path).inc()
+                obs.metrics.counter("repro_mining_sweep_slots_total",
+                                    path=path).inc(fl.sweep_slots)
                 m = obs.metrics
                 m.gauge("repro_mining_fused_merge_cap").set(merge_cap)
                 m.gauge("repro_mining_fused_fold_chunk").set(fold_chunk)
@@ -1104,9 +1124,13 @@ class MiningExecutor:
                 retries_total += retries
                 for member_parts, c in zip(parts, bucket_counts):
                     member_parts.append(c)
+            sweep_slots = self.bucket_sweep_slots(layout)
             self.obs.metrics.counter(
                 "repro_mining_launches_total",
                 path="per-bucket-multi").inc(len(layout.buckets))
+            self.obs.metrics.counter(
+                "repro_mining_sweep_slots_total",
+                path="per-bucket-multi").inc(sweep_slots)
             counts = tuple(
                 merge_partial_counts(p, merge_cap=self.merge_cap,
                                      warn_label="zone-layout bucket",
@@ -1116,6 +1140,7 @@ class MiningExecutor:
                 "path": "per-bucket-multi",
                 "launches": len(layout.buckets),
                 "spill_retries": retries_total,
+                "sweep_slots": sweep_slots,
                 "n_configs": len(params),
             }
             return MultiRunOutcome(counts=counts, stats=stats)
@@ -1169,6 +1194,8 @@ class MiningExecutor:
                 }
                 obs.metrics.counter("repro_mining_launches_total",
                                     path=path).inc()
+                obs.metrics.counter("repro_mining_sweep_slots_total",
+                                    path=path).inc(fl.sweep_slots)
                 return MultiRunOutcome(
                     counts=tuple(c for c, _ in out), stats=stats)
             for i, n_spilled in enumerate(spills):
@@ -1210,7 +1237,7 @@ class MiningExecutor:
                         f"or pick a divisor"
                     )
                 u, v, t, valid, signs = pad_zone_arrays(
-                    u, v, t, valid, signs, n_rows=z + (zc - z % zc))
+                    u, v, t, valid, signs, n_rows=_padded_zones(z, zc))
                 z = u.shape[0]
             sp.set(zone_chunk=zc)
             return self._run_bounded_multi(u, v, t, valid, signs, zc, params)
@@ -1279,11 +1306,19 @@ class MiningExecutor:
 
     def run_arrays(self, u, v, t, valid, signs, *,
                    label: str = "") -> CodeCounts:
-        """Mine raw [Z, E] zone arrays (+ [Z] signs) to signed code counts."""
+        """Mine raw [Z, E] zone arrays (+ [Z] signs) to signed code counts.
+
+        Spans: ``mine.launch`` around the whole bucket; inside it, on the
+        jitted legacy and hierarchical paths, ``mine.bucket_h2d`` (the
+        arrays put on the device) and ``mine.bucket_scan`` (the jitted scan
+        and in-bucket fold up to its counts, one span per spill attempt).
+        Each is synced at its end only when the tracer is on.
+        """
         u, v, t, valid, signs = (np.asarray(x)
                                  for x in (u, v, t, valid, signs))
         z, e = u.shape
-        with self.obs.tracer.span("mine.launch", z=z, e=e, label=label) as sp:
+        tracer = self.obs.tracer
+        with tracer.span("mine.launch", z=z, e=e, label=label) as sp:
             zc = self._zone_chunk_for(z, e)
             if zc and zc < z and z % zc != 0:
                 if self.pad_policy == "raise":
@@ -1296,11 +1331,18 @@ class MiningExecutor:
                         f"or pick a divisor"
                     )
                 u, v, t, valid, signs = pad_zone_arrays(
-                    u, v, t, valid, signs, n_rows=z + (zc - z % zc))
+                    u, v, t, valid, signs, n_rows=_padded_zones(z, zc))
                 z = u.shape[0]
 
             mode = self._agg_mode_for(zc, z)
             sp.set(agg=mode, zone_chunk=zc)
+            if self.spec.jittable and mode != "pipelined":
+                # the pipelined fold puts each chunk itself, behind the
+                # running one
+                with tracer.span("mine.bucket_h2d") as h2d:
+                    u, v, t, valid, signs = arrays = tuple(
+                        jnp.asarray(x) for x in (u, v, t, valid, signs))
+                    h2d.sync(arrays)
             if mode == "legacy":
                 counts = self._run_legacy(u, v, t, valid, signs, zc)
             else:
@@ -1316,12 +1358,13 @@ class MiningExecutor:
                 jnp.asarray(res.code), jnp.asarray(res.length),
                 jnp.asarray(signs),
             )
-        return _mine_jit(
-            jnp.asarray(u), jnp.asarray(v), jnp.asarray(t),
-            jnp.asarray(valid), jnp.asarray(signs),
-            delta=self.delta, l_max=self.l_max, scan=self.spec.scan,
-            zone_chunk=zc,
-        )
+        with self.obs.tracer.span("mine.bucket_scan") as sp:
+            counts = _mine_jit(
+                u, v, t, valid, signs, delta=self.delta, l_max=self.l_max,
+                scan=self.spec.scan, zone_chunk=zc,
+            )
+            sp.sync(counts)
+        return counts
 
     def _run_bounded(self, u, v, t, valid, signs, zc, mode) -> CodeCounts:
         """Hierarchical/pipelined fold with the merge-cap spill policy.
@@ -1342,12 +1385,15 @@ class MiningExecutor:
                 counts, spilled = self._fold_pipelined(
                     u, v, t, valid, signs, zc, merge_cap)
             else:
-                counts, spilled = _mine_jit_hier(
-                    jnp.asarray(u), jnp.asarray(v), jnp.asarray(t),
-                    jnp.asarray(valid), jnp.asarray(signs),
-                    delta=self.delta, l_max=self.l_max, scan=self.spec.scan,
-                    zone_chunk=zc, merge_cap=merge_cap,
-                )
+                # one span per attempt: a spill retry runs the scan again
+                with self.obs.tracer.span("mine.bucket_scan",
+                                          merge_cap=merge_cap) as sp:
+                    counts, spilled = _mine_jit_hier(
+                        u, v, t, valid, signs, delta=self.delta,
+                        l_max=self.l_max, scan=self.spec.scan,
+                        zone_chunk=zc, merge_cap=merge_cap,
+                    )
+                    sp.sync((counts, spilled))
             n_spilled = int(spilled)
             if n_spilled == 0:
                 return counts

@@ -264,3 +264,22 @@ def test_stream_pairs_are_split_and_their_launches_counted():
     solo = StreamingMiner(config=CFG)
     solo.ingest(g.u, g.v, g.t)
     assert solo.stats is None
+
+
+@pytest.mark.parametrize("fused", ["off", "on"])
+def test_engine_counts_sweep_slots_and_mirrors_them(fused):
+    import repro.obs as obs_mod
+
+    obs = obs_mod.enabled()
+    engine = PTMTEngine(CFG.with_updates(fused=fused, fused_backend="xla"),
+                        obs=obs)
+    g = _graph()
+    res = engine.discover(g)
+    run = res.layout["execution"]
+    assert run["sweep_slots"] > 0
+    assert engine.stats.sweep_slots == run["sweep_slots"]
+    engine.discover(g)
+    assert engine.stats.sweep_slots == 2 * run["sweep_slots"]
+    assert obs.metrics.counter("repro_mining_sweep_slots_total",
+                               path=run["path"]).value \
+        == engine.stats.sweep_slots

@@ -301,3 +301,98 @@ def test_cross_bucket_merge_is_named_merge():
     merged = ex_mod.merge_partial_counts(parts)
     assert _counts_dict(merged) == dict(
         oracle.count_codes(g.u, g.v, g.t, 60, 3))
+
+
+# ---------------------------------------------------------------------------
+# What the Phase-1 scan sweeps, and the spans inside a bucket launch.
+# ---------------------------------------------------------------------------
+
+
+def _bucketed_layout(seed=3):
+    g = random_graph(seed, 400, 20, 3_000)
+    plan = tzp.plan_zones(g, delta=60, l_max=3, omega=2)
+    layout = tzp.build_zone_layout(g, plan, layout="bucketed")
+    assert layout.n_buckets > 1
+    return layout
+
+
+@pytest.mark.parametrize("zone_chunk", [None, 3])
+def test_per_bucket_sweep_slots_are_padded_zones_by_e_squared(zone_chunk):
+    layout = _bucketed_layout()
+    ex = MiningExecutor(delta=60, l_max=3, zone_chunk=zone_chunk,
+                        fused="off")
+    _, stats = ex.run_layout(layout)
+    chunk = zone_chunk or 1
+    # a bucket of more zones than the chunk is padded to whole chunks
+    rows = [-(-b.n_zones // chunk) * chunk if b.n_zones > chunk
+            else b.n_zones for b in layout.buckets]
+    want = sum(z * b.e_cap ** 2 for z, b in zip(rows, layout.buckets))
+    assert stats["path"] == "per-bucket"
+    assert stats["sweep_slots"] == ex.bucket_sweep_slots(layout) == want
+    if zone_chunk:
+        assert want > layout.sweep_slots       # padding rows are swept
+
+
+def test_fused_sweep_slots_are_the_block_descriptors_sum():
+    from repro.core import planner
+
+    layout = _bucketed_layout()
+    ex = MiningExecutor(delta=60, l_max=3, backend="xla")
+    _, stats = ex.run_layout(layout)
+    blk, fold_chunk, _ = ex._fused_geometry(layout)
+    fl = tzp.concat_layout(layout, blk=blk, pad_slots_to=fold_chunk,
+                           delta=60, l_max=3, bounds=ex.fused_bounds)
+    assert stats["path"] == "fused" and stats["launches"] == 1
+    assert stats["sweep_slots"] == blk * int((fl.hi - fl.lo).sum()) \
+        == planner.fused_sweep_slots(fl.lo, fl.hi, blk)
+
+
+@pytest.mark.parametrize("agg", ["legacy", "hierarchical"])
+def test_bucket_h2d_and_scan_nest_under_the_launch(agg):
+    import repro.obs as obs_mod
+
+    obs = obs_mod.enabled()
+    layout = _bucketed_layout()
+    ex = MiningExecutor(delta=60, l_max=3, agg=agg, zone_chunk=2,
+                        fused="off", obs=obs)
+    ex.run_layout(layout)
+    events = obs.tracer.events()
+    launches = {e["args"]["span_id"]: e for e in events
+                if e["name"] == "mine.launch"}
+    assert len(launches) == layout.n_buckets
+    for name in ("mine.bucket_h2d", "mine.bucket_scan"):
+        inner = [e for e in events if e["name"] == name]
+        assert sorted(e["args"]["parent_id"] for e in inner) == \
+            sorted(launches)
+        for e in inner:
+            launch = launches[e["args"]["parent_id"]]
+            assert launch["ts"] <= e["ts"]
+            assert e["ts"] + e["dur"] <= launch["ts"] + launch["dur"]
+    h2d = {e["args"]["parent_id"]: e for e in events
+           if e["name"] == "mine.bucket_h2d"}
+    for e in events:
+        if e["name"] == "mine.bucket_scan":
+            before = h2d[e["args"]["parent_id"]]
+            assert before["ts"] + before["dur"] <= e["ts"]
+
+
+def test_disabled_tracer_records_nothing_and_adds_no_sync(monkeypatch):
+    import jax
+
+    import repro.obs as obs_mod
+
+    layout = _bucketed_layout()
+    synced = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: synced.append(x) or real(x))
+    ex = MiningExecutor(delta=60, l_max=3, fused="off")
+    want = _counts_dict(ex.run_layout(layout).counts)
+    assert synced == [] and ex.obs.tracer.events() == []
+    # the same run traced syncs each span it opens
+    obs = obs_mod.enabled()
+    ex = MiningExecutor(delta=60, l_max=3, fused="off", obs=obs)
+    assert _counts_dict(ex.run_layout(layout).counts) == want
+    names = [e["name"] for e in obs.tracer.events()]
+    assert len(synced) >= names.count("mine.bucket_h2d") + \
+        names.count("mine.bucket_scan") > 0
