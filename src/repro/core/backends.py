@@ -220,7 +220,8 @@ def _pallas_mem_model(e_cap: int, l_max: int) -> int:
 register_backend(
     "ref", _load_ref,
     jittable=True, grade="reference",
-    description="vectorized jnp lax.scan expansion (exact, any device)",
+    description=("vectorized jnp expansion, its loop bounded by the "
+                 "Lemma-4.1 horizon (exact, any device)"),
     mem_model=_ref_mem_model,
     supports_comine=True,
 )
@@ -238,7 +239,7 @@ register_backend(
 register_backend(
     "xla", _load_ref,
     jittable=True, grade="reference",
-    description=("compiled XLA lowering: reference dense scan plus a pure "
+    description=("compiled XLA lowering: reference scan plus a pure "
                  "lax fused flat scan (fast on CPU, no interpreter)"),
     mem_model=_ref_mem_model,
     fused_loader=_load_xla_fused,

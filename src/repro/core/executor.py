@@ -91,6 +91,32 @@ class MultiRunOutcome(NamedTuple):
     counts: tuple          # tuple[CodeCounts, ...], aligned with params
     stats: dict
 
+
+class _BucketRun(NamedTuple):
+    """One bucket launch: its counts and what its scans visited.
+
+    ``trips`` (a device scalar, or an int from a host scan) sums the
+    launch's per-chunk scan trips; every chunk has ``rows`` zone rows of
+    ``e`` slots, so the candidate-steps are ``rows * e * trips``.
+    """
+
+    counts: object         # CodeCounts, or a tuple of them when co-mined
+    trips: object
+    rows: int
+    e: int
+
+
+def _sweep_slots(runs) -> int:
+    """Candidate-steps a layout's bucket launches visited, read in one
+    copy after their counts reached the host."""
+    trips = jax.device_get([r.trips for r in runs])
+    return sum(r.rows * r.e * int(n) for r, n in zip(runs, trips))
+
+
+def _chunk_rows(z: int, zc: int) -> int:
+    """Zone rows one scan of a (padded) ``z``-row batch sees."""
+    return zc if zc and zc < z else z
+
 #: Fused single-launch dispatch policy for ``run_layout``: "auto" fuses
 #: whenever the backend publishes a bucket-native flat kernel, "on"
 #: requires one (erroring otherwise), "off" keeps the per-bucket path.
@@ -171,31 +197,39 @@ class ZoneOverflowError(RuntimeError):
     """
 
 
+def _trips(res, e: int):
+    """Trips a zone scan ran: the count it reports, else a full sweep of
+    ``e`` slots (backends that do not bound their loop)."""
+    return e if res.trips is None else res.trips
+
+
 def _chunked_scan(scan, u, v, t, valid, *, delta, l_max, zone_chunk):
     """Sweep a [Z, E] zone batch, optionally in chunks of ``zone_chunk``.
 
     Traceable; shapes are static here, so divisibility is checked at trace
     time (the executor's host path pads beforehand under pad_policy='pad').
+    Returns ``(codes, lengths, trips)``, ``trips`` summed over the chunks.
     """
 
     def chunk_fn(args):
         cu, cv, ct, cvalid = args
         with jax.named_scope("zone_scan"):
             res = scan(cu, cv, ct, cvalid, delta=delta, l_max=l_max)
-        return res.code, res.length
+        return res.code, res.length, jnp.int32(_trips(res, u.shape[1]))
 
     z = u.shape[0]
     if zone_chunk and zone_chunk < z:
         nchunk = _n_chunks(z, zone_chunk)
         reshape = lambda x: x.reshape(nchunk, zone_chunk, *x.shape[1:])
-        codes, lengths = jax.lax.map(
+        codes, lengths, trips = jax.lax.map(
             chunk_fn, (reshape(u), reshape(v), reshape(t), reshape(valid))
         )
         codes = codes.reshape(z, *codes.shape[2:])
         lengths = lengths.reshape(z, *lengths.shape[2:])
+        trips = trips.sum()
     else:
-        codes, lengths = chunk_fn((u, v, t, valid))
-    return codes, lengths
+        codes, lengths, trips = chunk_fn((u, v, t, valid))
+    return codes, lengths, trips
 
 
 def _padded_zones(z: int, zone_chunk: int) -> int:
@@ -224,11 +258,12 @@ def _hier_fold(scan, u, v, t, valid, signs, *, delta, l_max, zone_chunk,
     (``aggregate_zones``); the partial tables fold through a bounded-width
     carry (``merge_bounded``) inside ``lax.scan``, so at no point do all
     Z*C candidate codes coexist.  Returns ``(CodeCounts[merge_cap],
-    spilled)`` — ``spilled > 0`` means ``merge_cap`` was too small and the
-    result is inexact (the host retries with a doubled cap).
+    spilled, trips)`` — ``spilled > 0`` means ``merge_cap`` was too small
+    and the result is inexact (the host retries with a doubled cap);
+    ``trips`` is the chunks' scan trips, summed.
     """
     z = u.shape[0]
-    zc = zone_chunk if (zone_chunk and zone_chunk < z) else z
+    zc = _chunk_rows(z, zone_chunk)
     nchunk = _n_chunks(z, zc)
     limbs = encoding.n_limbs(l_max)
     reshape = lambda x: x.reshape(nchunk, zc, *x.shape[1:])
@@ -236,7 +271,7 @@ def _hier_fold(scan, u, v, t, valid, signs, *, delta, l_max, zone_chunk,
           signs.reshape(nchunk, zc))
 
     def body(carry, chunk):
-        counts, spilled = carry
+        counts, spilled, trips = carry
         cu, cv, ct, cvalid, csigns = chunk
         with jax.named_scope("zone_scan"):
             res = scan(cu, cv, ct, cvalid, delta=delta, l_max=l_max)
@@ -244,11 +279,13 @@ def _hier_fold(scan, u, v, t, valid, signs, *, delta, l_max, zone_chunk,
             part = aggregation.aggregate_zones(res.code, res.length, csigns)
             merged, spill = aggregation.merge_bounded(counts, part,
                                                       cap=merge_cap)
-        return (merged, spilled + spill), None
+        return (merged, spilled + spill,
+                trips + _trips(res, u.shape[1])), None
 
-    init = (aggregation.empty_counts(merge_cap, limbs), jnp.int32(0))
-    (counts, spilled), _ = jax.lax.scan(body, init, xs)
-    return counts, spilled
+    init = (aggregation.empty_counts(merge_cap, limbs), jnp.int32(0),
+            jnp.int32(0))
+    (counts, spilled, trips), _ = jax.lax.scan(body, init, xs)
+    return counts, spilled, trips
 
 
 @functools.partial(
@@ -261,13 +298,14 @@ def _mine_jit(u, v, t, valid, signs, *, delta, l_max, scan, zone_chunk):
     executor instance with the same (scan fn, delta, l_max, zone_chunk,
     batch shape) reuses one executable.  The cache is keyed on the resolved
     scan *callable*, not the backend name, so re-registering a backend
-    (``overwrite=True``) cannot serve a stale executable.
+    (``overwrite=True``) cannot serve a stale executable.  Returns
+    ``(counts, trips)``.
     """
-    codes, lengths = _chunked_scan(
+    codes, lengths, trips = _chunked_scan(
         scan, u, v, t, valid, delta=delta, l_max=l_max, zone_chunk=zone_chunk
     )
     with jax.named_scope("fold"):
-        return aggregation.aggregate_zones(codes, lengths, signs)
+        return aggregation.aggregate_zones(codes, lengths, signs), trips
 
 
 @functools.partial(
@@ -284,15 +322,15 @@ def _mine_jit_hier(u, v, t, valid, signs, *, delta, l_max, scan, zone_chunk,
 @functools.partial(
     jax.jit,
     static_argnames=("delta", "l_max", "scan", "merge_cap"),
-    donate_argnums=(0, 1),
+    donate_argnums=(0, 1, 2),
 )
-def _pipeline_step(carry, spilled, u, v, t, valid, signs, *, delta, l_max,
-                   scan, merge_cap):
+def _pipeline_step(carry, spilled, trips, u, v, t, valid, signs, *, delta,
+                   l_max, scan, merge_cap):
     """One pipelined chunk: scan + partial count + bounded merge.
 
-    The carry (and spill counter) are donated — XLA reuses their buffers in
-    place, so the resident aggregation state stays a single ``merge_cap``
-    table no matter how many chunks stream through.
+    The carry (and the spill and trip counters) are donated — XLA reuses
+    their buffers in place, so the resident aggregation state stays a
+    single ``merge_cap`` table no matter how many chunks stream through.
     """
     with jax.named_scope("zone_scan"):
         res = scan(u, v, t, valid, delta=delta, l_max=l_max)
@@ -300,7 +338,7 @@ def _pipeline_step(carry, spilled, u, v, t, valid, signs, *, delta, l_max,
         part = aggregation.aggregate_zones(res.code, res.length, signs)
         merged, spill = aggregation.merge_bounded(carry, part,
                                                   cap=merge_cap)
-    return merged, spilled + spill
+    return merged, spilled + spill, trips + _trips(res, u.shape[1])
 
 
 @functools.partial(
@@ -390,10 +428,11 @@ def _mine_multi_jit(u, v, t, valid, signs, *, delta, l_max, scan, zone_chunk,
     ONE ``with_ts`` dominating scan per chunk; each member of ``params``
     (a tuple of ``(delta_i, l_max_i)``) folds its derived candidate view
     through its own bounded merge carry.  Returns a tuple of
-    ``(CodeCounts, spilled)`` pairs aligned with ``params``.
+    ``(CodeCounts, spilled)`` pairs aligned with ``params``, and the
+    chunks' scan trips, summed.
     """
     z = u.shape[0]
-    zc = zone_chunk if (zone_chunk and zone_chunk < z) else z
+    zc = _chunk_rows(z, zone_chunk)
     nchunk = _n_chunks(z, zc)
     limbs = encoding.n_limbs(l_max)
     reshape = lambda x: x.reshape(nchunk, zc, *x.shape[1:])
@@ -401,13 +440,14 @@ def _mine_multi_jit(u, v, t, valid, signs, *, delta, l_max, scan, zone_chunk,
           signs.reshape(nchunk, zc))
 
     def body(carry, chunk):
+        members, trips = carry
         cu, cv, ct, cvalid, csigns = chunk
         with jax.named_scope("zone_scan"):
             res = scan(cu, cv, ct, cvalid, delta=delta, l_max=l_max,
                        with_ts=True)
         new_carry = []
         with jax.named_scope("fold"):
-            for (d_i, l_i), (counts, spilled), cap in zip(params, carry,
+            for (d_i, l_i), (counts, spilled), cap in zip(params, members,
                                                           merge_caps):
                 code_i, len_i = _derive_member(
                     res.code, res.length, res.ts,
@@ -416,12 +456,12 @@ def _mine_multi_jit(u, v, t, valid, signs, *, delta, l_max, scan, zone_chunk,
                 merged, spill = aggregation.merge_bounded(counts, part,
                                                           cap=cap)
                 new_carry.append((merged, spilled + spill))
-        return tuple(new_carry), None
+        return (tuple(new_carry), trips + _trips(res, u.shape[1])), None
 
     init = tuple(
         (aggregation.empty_counts(cap, limbs), jnp.int32(0))
         for cap in merge_caps)
-    out, _ = jax.lax.scan(body, init, xs)
+    out, _ = jax.lax.scan(body, (init, jnp.int32(0)), xs)
     return out
 
 
@@ -654,13 +694,6 @@ class MiningExecutor:
         return (self.backend, self.delta, self.l_max, z, e, zc, mode,
                 merge_cap)
 
-    def bucket_sweep_slots(self, layout: ZoneBatchLayout) -> int:
-        """Candidate-steps the per-bucket Phase-1 scans visit: each padded
-        zone row of a ``[z, e]`` bucket sweeps ``e`` steps over ``e``
-        slots, so ``sum(z_padded * e * e)``, from shapes alone."""
-        return sum(_padded_zones(b.n_zones, self._zone_chunk_for(
-            b.n_zones, b.e_cap)) * b.e_cap ** 2 for b in layout.buckets)
-
     # -- capacity resolution ------------------------------------------------
 
     def capacity_plan(self, n_zones: int, e_cap: int):
@@ -726,7 +759,7 @@ class MiningExecutor:
         (static) zone count does not divide ``zone_chunk``.
         """
         self._require_jittable()
-        codes, lengths = _chunked_scan(
+        codes, lengths, _ = _chunked_scan(
             self.spec.scan, u, v, t, valid,
             delta=self.delta, l_max=self.l_max, zone_chunk=self.zone_chunk,
         )
@@ -747,11 +780,12 @@ class MiningExecutor:
         zc = self._zone_chunk_for(z, e)
         if self._agg_mode_for(zc, z) == "legacy":
             return self.scan_aggregate(u, v, t, valid, signs), jnp.int32(0)
-        return _hier_fold(
+        counts, spilled, _ = _hier_fold(
             self.spec.scan, u, v, t, valid, signs,
             delta=self.delta, l_max=self.l_max, zone_chunk=zc,
             merge_cap=self._merge_cap_for(zc, z, e),
         )
+        return counts, spilled
 
     # -- host-level entry points -------------------------------------------
 
@@ -899,16 +933,20 @@ class MiningExecutor:
         self.check_layout_overflow(layout, allow_overflow=allow_overflow)
         with self.obs.tracer.span("mine.layout", path="per-bucket",
                                   buckets=layout.n_buckets):
-            parts = [
-                self.run_arrays(b.u, b.v, b.t, b.valid, b.sign,
-                                label=b.label)
+            runs = [
+                self._run_bucket(b.u, b.v, b.t, b.valid, b.sign,
+                                 label=b.label)
                 for b in layout.buckets
             ]
+            counts = merge_partial_counts([r.counts for r in runs],
+                                          merge_cap=self.merge_cap,
+                                          warn_label="zone-layout bucket",
+                                          obs=self.obs)
             stats = {
                 "path": "per-bucket",
                 "launches": len(layout.buckets),
                 "spill_retries": 0,
-                "sweep_slots": self.bucket_sweep_slots(layout),
+                "sweep_slots": _sweep_slots(runs),
             }
             self.obs.metrics.counter(
                 "repro_mining_launches_total",
@@ -916,9 +954,6 @@ class MiningExecutor:
             self.obs.metrics.counter(
                 "repro_mining_sweep_slots_total",
                 path="per-bucket").inc(stats["sweep_slots"])
-            counts = merge_partial_counts(parts, merge_cap=self.merge_cap,
-                                          warn_label="zone-layout bucket",
-                                          obs=self.obs)
             return RunOutcome(counts=counts, stats=stats)
 
     # -- fused single-launch path -------------------------------------------
@@ -1116,26 +1151,25 @@ class MiningExecutor:
         with self.obs.tracer.span("mine.layout", path="per-bucket-multi",
                                   buckets=layout.n_buckets,
                                   n_configs=len(params)):
-            parts: list[list[CodeCounts]] = [[] for _ in params]
+            runs = []
             retries_total = 0
             for b in layout.buckets:
-                bucket_counts, retries = self._run_arrays_multi(
+                run, retries = self._run_arrays_multi(
                     b.u, b.v, b.t, b.valid, b.sign, params, label=b.label)
+                runs.append(run)
                 retries_total += retries
-                for member_parts, c in zip(parts, bucket_counts):
-                    member_parts.append(c)
-            sweep_slots = self.bucket_sweep_slots(layout)
+            counts = tuple(
+                merge_partial_counts(list(p), merge_cap=self.merge_cap,
+                                     warn_label="zone-layout bucket",
+                                     obs=self.obs)
+                for p in zip(*(r.counts for r in runs)))
+            sweep_slots = _sweep_slots(runs)
             self.obs.metrics.counter(
                 "repro_mining_launches_total",
                 path="per-bucket-multi").inc(len(layout.buckets))
             self.obs.metrics.counter(
                 "repro_mining_sweep_slots_total",
                 path="per-bucket-multi").inc(sweep_slots)
-            counts = tuple(
-                merge_partial_counts(p, merge_cap=self.merge_cap,
-                                     warn_label="zone-layout bucket",
-                                     obs=self.obs)
-                for p in parts)
             stats = {
                 "path": "per-bucket-multi",
                 "launches": len(layout.buckets),
@@ -1214,7 +1248,8 @@ class MiningExecutor:
 
     def _run_arrays_multi(self, u, v, t, valid, signs, params, *,
                           label: str = ""):
-        """Co-mine raw [Z, E] zone arrays; returns (counts tuple, retries).
+        """Co-mine raw [Z, E] zone arrays; returns ``(_BucketRun,
+        retries)``, the run's counts a tuple aligned with ``params``.
 
         Mirrors :meth:`run_arrays`'s pad/chunk resolution, but always takes
         the bounded hierarchical fold — the multi path has no legacy
@@ -1240,10 +1275,13 @@ class MiningExecutor:
                     u, v, t, valid, signs, n_rows=_padded_zones(z, zc))
                 z = u.shape[0]
             sp.set(zone_chunk=zc)
-            return self._run_bounded_multi(u, v, t, valid, signs, zc, params)
+            counts, trips, retries = self._run_bounded_multi(
+                u, v, t, valid, signs, zc, params)
+            return _BucketRun(counts, trips, _chunk_rows(z, zc), e), retries
 
     def _run_bounded_multi(self, u, v, t, valid, signs, zc, params):
-        """Multi-config bounded fold with per-member spill/retry."""
+        """Multi-config bounded fold with per-member spill/retry;
+        returns ``(counts tuple, trips, retries)``."""
         z, e = u.shape
         cap_ceiling = z * e + 1
         base_cap = min(self._merge_cap_for(zc, z, e), cap_ceiling)
@@ -1251,10 +1289,10 @@ class MiningExecutor:
         retries = 0
         while True:
             if not self.spec.jittable:
-                out = self._fold_host_scan_multi(u, v, t, valid, signs, zc,
-                                                 params, caps)
+                out, trips = self._fold_host_scan_multi(
+                    u, v, t, valid, signs, zc, params, caps)
             else:
-                out = _mine_multi_jit(
+                out, trips = _mine_multi_jit(
                     jnp.asarray(u), jnp.asarray(v), jnp.asarray(t),
                     jnp.asarray(valid), jnp.asarray(signs),
                     delta=self.delta, l_max=self.l_max, scan=self.spec.scan,
@@ -1262,7 +1300,7 @@ class MiningExecutor:
                 )
             spills = [int(sp) for _, sp in out]
             if not any(spills):
-                return tuple(c for c, _ in out), retries
+                return tuple(c for c, _ in out), trips, retries
             for i, n_spilled in enumerate(spills):
                 if n_spilled:
                     need = max(2 * caps[i], caps[i] + n_spilled, 8)
@@ -1278,19 +1316,22 @@ class MiningExecutor:
             retries += 1
 
     def _fold_host_scan_multi(self, u, v, t, valid, signs, zc, params, caps):
-        """Chunked multi-config fold for host-only backends."""
+        """Chunked multi-config fold for host-only backends; returns
+        ``(carries, trips)``."""
         z, e = u.shape
-        zc = zc if (zc and zc < z) else z
+        zc = _chunk_rows(z, zc)
         nchunk = _n_chunks(z, zc)
         limbs = encoding.n_limbs(self.l_max)
         carries = [
             (aggregation.empty_counts(cap, limbs), jnp.int32(0))
             for cap in caps]
+        trips = 0
         for i in range(nchunk):
             sl = slice(i * zc, (i + 1) * zc)
             res = self.spec.scan(u[sl], v[sl], t[sl], valid[sl],
                                  delta=self.delta, l_max=self.l_max,
                                  with_ts=True)
+            trips += int(_trips(res, e))
             codes = jnp.asarray(res.code)
             lengths = jnp.asarray(res.length)
             ts = jnp.asarray(res.ts)
@@ -1302,11 +1343,16 @@ class MiningExecutor:
                     d_i=d_i, l_i=l_i, delta=self.delta, l_max=self.l_max,
                     merge_cap=cap,
                 )
-        return carries
+        return carries, trips
 
     def run_arrays(self, u, v, t, valid, signs, *,
                    label: str = "") -> CodeCounts:
-        """Mine raw [Z, E] zone arrays (+ [Z] signs) to signed code counts.
+        """Mine raw [Z, E] zone arrays (+ [Z] signs) to signed code counts."""
+        return self._run_bucket(u, v, t, valid, signs, label=label).counts
+
+    def _run_bucket(self, u, v, t, valid, signs, *,
+                    label: str = "") -> _BucketRun:
+        """One bucket launch of :meth:`run_arrays`, with its scan trips.
 
         Spans: ``mine.launch`` around the whole bucket; inside it, on the
         jitted legacy and hierarchical paths, ``mine.bucket_h2d`` (the
@@ -1344,30 +1390,33 @@ class MiningExecutor:
                         jnp.asarray(x) for x in (u, v, t, valid, signs))
                     h2d.sync(arrays)
             if mode == "legacy":
-                counts = self._run_legacy(u, v, t, valid, signs, zc)
+                counts, trips = self._run_legacy(u, v, t, valid, signs, zc)
             else:
-                counts = self._run_bounded(u, v, t, valid, signs, zc, mode)
+                counts, trips = self._run_bounded(u, v, t, valid, signs, zc,
+                                                  mode)
             sp.sync(counts)
-            return counts
+            return _BucketRun(counts, trips, _chunk_rows(z, zc), e)
 
-    def _run_legacy(self, u, v, t, valid, signs, zc) -> CodeCounts:
+    def _run_legacy(self, u, v, t, valid, signs, zc):
+        """Whole-batch fold; returns ``(counts, trips)``."""
         if not self.spec.jittable:
             res = self.spec.scan(u, v, t, valid,
                                  delta=self.delta, l_max=self.l_max)
             return aggregation.aggregate_zones(
                 jnp.asarray(res.code), jnp.asarray(res.length),
                 jnp.asarray(signs),
-            )
+            ), _trips(res, u.shape[1])
         with self.obs.tracer.span("mine.bucket_scan") as sp:
-            counts = _mine_jit(
+            counts, trips = _mine_jit(
                 u, v, t, valid, signs, delta=self.delta, l_max=self.l_max,
                 scan=self.spec.scan, zone_chunk=zc,
             )
             sp.sync(counts)
-        return counts
+        return counts, trips
 
-    def _run_bounded(self, u, v, t, valid, signs, zc, mode) -> CodeCounts:
-        """Hierarchical/pipelined fold with the merge-cap spill policy.
+    def _run_bounded(self, u, v, t, valid, signs, zc, mode):
+        """Hierarchical/pipelined fold with the merge-cap spill policy;
+        returns ``(counts, trips)``.
 
         Spills are exact signals, so retrying with a doubled cap is
         lossless; ``merge_cap >= z*e + 1`` can never spill (at most z*e
@@ -1379,16 +1428,16 @@ class MiningExecutor:
         merge_cap = min(self._merge_cap_for(zc, z, e), cap_ceiling)
         while True:
             if not self.spec.jittable:
-                counts, spilled = self._fold_host_scan(
+                counts, spilled, trips = self._fold_host_scan(
                     u, v, t, valid, signs, zc, merge_cap)
             elif mode == "pipelined":
-                counts, spilled = self._fold_pipelined(
+                counts, spilled, trips = self._fold_pipelined(
                     u, v, t, valid, signs, zc, merge_cap)
             else:
                 # one span per attempt: a spill retry runs the scan again
                 with self.obs.tracer.span("mine.bucket_scan",
                                           merge_cap=merge_cap) as sp:
-                    counts, spilled = _mine_jit_hier(
+                    counts, spilled, trips = _mine_jit_hier(
                         u, v, t, valid, signs, delta=self.delta,
                         l_max=self.l_max, scan=self.spec.scan,
                         zone_chunk=zc, merge_cap=merge_cap,
@@ -1396,7 +1445,7 @@ class MiningExecutor:
                     sp.sync((counts, spilled))
             n_spilled = int(spilled)
             if n_spilled == 0:
-                return counts
+                return counts, trips
             # cap+spilled approximates the live-code population (a code cut
             # in several steps is counted per step, so it can only
             # overshoot the next guess); exactness is re-checked each
@@ -1422,7 +1471,7 @@ class MiningExecutor:
         table.
         """
         z, e = u.shape
-        zc = zc if (zc and zc < z) else z
+        zc = _chunk_rows(z, zc)
         nchunk = _n_chunks(z, zc)
         limbs = encoding.n_limbs(self.l_max)
 
@@ -1433,16 +1482,17 @@ class MiningExecutor:
 
         carry = aggregation.empty_counts(merge_cap, limbs)
         spilled = jnp.zeros((), jnp.int32)
+        trips = jnp.zeros((), jnp.int32)
         nxt = put(0)
         for i in range(nchunk):
             cur = nxt
-            carry, spilled = _pipeline_step(
-                carry, spilled, *cur, delta=self.delta, l_max=self.l_max,
-                scan=self.spec.scan, merge_cap=merge_cap,
+            carry, spilled, trips = _pipeline_step(
+                carry, spilled, trips, *cur, delta=self.delta,
+                l_max=self.l_max, scan=self.spec.scan, merge_cap=merge_cap,
             )
             if i + 1 < nchunk:
                 nxt = put(i + 1)    # async H2D behind the running chunk
-        return carry, spilled
+        return carry, spilled, trips
 
     def _fold_host_scan(self, u, v, t, valid, signs, zc, merge_cap):
         """Chunked fold for host-only backends (scan outside jit).
@@ -1452,18 +1502,20 @@ class MiningExecutor:
         same bounded carry as the device paths.
         """
         z, e = u.shape
-        zc = zc if (zc and zc < z) else z
+        zc = _chunk_rows(z, zc)
         nchunk = _n_chunks(z, zc)
         limbs = encoding.n_limbs(self.l_max)
         carry = aggregation.empty_counts(merge_cap, limbs)
         spilled = jnp.zeros((), jnp.int32)
+        trips = 0
         for i in range(nchunk):
             sl = slice(i * zc, (i + 1) * zc)
             res = self.spec.scan(u[sl], v[sl], t[sl], valid[sl],
                                  delta=self.delta, l_max=self.l_max)
+            trips += int(_trips(res, e))
             carry, spilled = _merge_chunk_jit(
                 carry, spilled, jnp.asarray(res.code),
                 jnp.asarray(res.length), jnp.asarray(signs[sl]),
                 merge_cap=merge_cap,
             )
-        return carry, spilled
+        return carry, spilled, trips

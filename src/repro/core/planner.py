@@ -286,25 +286,16 @@ def layout_peak_bytes(plans: dict[tuple[int, int], CapacityPlan]) -> int:
     return max((p.est_peak_bytes for p in plans.values()), default=0)
 
 
-def padded_sweep_slots(bucket_shapes) -> int:
-    """Padded pairwise sweep work ``sum(Z_b * e_cap_b**2)`` of a layout.
-
-    The dense layout's cost is ``Z * e_cap_max**2``; the ratio of the two
-    is the padding-waste model the zone-layout benchmark reports
-    (EXPERIMENTS.md §Zone batch layout).
-    """
-    return sum(int(z) * int(e) ** 2 for z, e in bucket_shapes)
-
-
 def fused_sweep_slots(lo, hi, blk: int) -> int:
     """Dispatched sweep work of a fused flat stream: each candidate block
     of ``blk`` lanes streams its ``[lo, hi)`` window once, so the slot-cell
     cost is ``blk * sum(hi - lo)``.
 
-    The fused analog of :func:`padded_sweep_slots`, and the quantity
-    host-planned compaction attacks: tightening ``hi`` to the Lemma-4.1
-    horizon cut (``tzp.concat_layout(bounds="live")``) shrinks this model
-    directly, and with it the compiled kernel's chunk traffic below.
+    The fused analog of :attr:`repro.core.tzp.ZoneBatchLayout.sweep_slots`,
+    and the quantity host-planned compaction attacks: tightening ``hi`` to
+    the Lemma-4.1 horizon cut (``tzp.concat_layout(bounds="live")``)
+    shrinks this model directly, and with it the compiled kernel's chunk
+    traffic below.
     """
     return int(blk) * int(sum(int(h) - int(l) for l, h in zip(lo, hi)))
 

@@ -288,6 +288,28 @@ class ZoneBatch:
         return self.valid_edges / max(self.padded_slots, 1)
 
 
+def horizon_trips_np(t, valid, *, span: int) -> int:
+    """NumPy twin of :func:`repro.core.expansion.horizon_trips`: the trips
+    a horizon-bounded scan of a ``[Z, E]`` batch runs, ``1 + max(h_i - i)``
+    over valid lanes (0 for none), in int64 so nothing saturates."""
+    t = np.asarray(t, np.int64)
+    valid = np.asarray(valid, bool)
+    if not valid.any():
+        return 0
+    z, e = t.shape
+    lo = int(t[valid].min())
+    past = int(t[valid].max()) + int(span) + 1   # beyond every bound
+    suffix = np.minimum.accumulate(
+        np.where(valid, t, past)[:, ::-1], axis=1)[:, ::-1]
+    # shift row r by r * stride so one searchsorted serves every row
+    stride = past - lo + 1
+    shift = np.arange(z, dtype=np.int64)[:, None] * stride
+    count = np.searchsorted((suffix - lo + shift).ravel(),
+                            (t + span - lo + shift).ravel(), side="right")
+    h = count.reshape(z, e) - np.arange(z, dtype=np.int64)[:, None] * e - 1
+    return int((h - np.arange(e))[valid].max()) + 1
+
+
 def _round_up(x: int, mult: int) -> int:
     return ((x + mult - 1) // mult) * mult
 
@@ -400,11 +422,13 @@ class ZoneBatchLayout:
     Buckets are ordered by ascending capacity and each is a self-contained
     padded batch; signed aggregation is associative over zones (Lemma 4.2),
     so mining buckets independently and merging the partial count tables is
-    exact.
+    exact.  ``horizon`` is the plan's ``l_b = l_max * delta``, the span
+    past a candidate's seed that the Phase-1 scan walks.
     """
 
     kind: str
     buckets: tuple[ZoneBatch, ...]
+    horizon: int
 
     @property
     def n_buckets(self) -> int:
@@ -440,12 +464,12 @@ class ZoneBatchLayout:
 
     @property
     def sweep_slots(self) -> int:
-        """Padded pairwise sweep work — the dense O(e_cap²) cost model the
-        bucketing attacks.  One formula, owned by the planner
-        (:func:`repro.core.planner.padded_sweep_slots`)."""
-        from . import planner
-
-        return planner.padded_sweep_slots(self.bucket_shapes())
+        """Candidate-steps the unchunked per-bucket scans visit:
+        ``sum(z * e_cap * trips)``, each bucket's trips from
+        :func:`horizon_trips_np` (at most ``e_cap``, the dense sweep)."""
+        return sum(b.n_zones * b.e_cap
+                   * horizon_trips_np(b.t, b.valid, span=self.horizon)
+                   for b in self.buckets)
 
     def bucket_shapes(self) -> tuple[tuple[int, int], ...]:
         """Per-bucket ``(n_zones, e_cap)`` — the compile-cache geometry."""
@@ -750,7 +774,8 @@ def build_zone_layout(
         dense = build_zone_batch(
             graph, plan, e_cap=e_cap, pad_zones_to=pad_zones_to,
             pad_edges_to=pad_edges_to, n_shards=n_shards, label="dense")
-        return ZoneBatchLayout(kind="dense", buckets=(dense,))
+        return ZoneBatchLayout(kind="dense", buckets=(dense,),
+                               horizon=plan.l_b)
 
     max_cap = dense_cap(plan, e_cap=e_cap, pad_edges_to=pad_edges_to)
     nonempty = np.flatnonzero(np.asarray(plan.count) > 0)
@@ -763,7 +788,8 @@ def build_zone_layout(
             graph, _select_plan(plan, nonempty), e_cap=pad_edges_to,
             pad_zones_to=pad_zones_to, pad_edges_to=pad_edges_to,
             n_shards=n_shards, label=f"cap{pad_edges_to}")
-        return ZoneBatchLayout(kind="bucketed", buckets=(inert,))
+        return ZoneBatchLayout(kind="bucketed", buckets=(inert,),
+                               horizon=plan.l_b)
     caps = bucket_caps(plan.count[nonempty], max_cap=max_cap,
                        pad_edges_to=pad_edges_to)
     buckets = []
@@ -778,4 +804,5 @@ def build_zone_layout(
         perm = np.where(batch.perm >= 0,
                         idx[np.clip(batch.perm, 0, len(idx) - 1)], -1)
         buckets.append(dataclasses.replace(batch, perm=perm))
-    return ZoneBatchLayout(kind="bucketed", buckets=tuple(buckets))
+    return ZoneBatchLayout(kind="bucketed", buckets=tuple(buckets),
+                           horizon=plan.l_b)

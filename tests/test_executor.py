@@ -308,29 +308,80 @@ def test_cross_bucket_merge_is_named_merge():
 # ---------------------------------------------------------------------------
 
 
-def _bucketed_layout(seed=3):
+def _bucketed_layout(seed=3, omega=2):
     g = random_graph(seed, 400, 20, 3_000)
-    plan = tzp.plan_zones(g, delta=60, l_max=3, omega=2)
+    plan = tzp.plan_zones(g, delta=60, l_max=3, omega=omega)
     layout = tzp.build_zone_layout(g, plan, layout="bucketed")
     assert layout.n_buckets > 1
     return layout
 
 
+def _horizon_trips(t, valid, span):
+    """``1 + max(h_i - i)`` over valid lanes, row by row: ``h_i`` is the
+    last slot whose suffix minimum of valid timestamps is within ``span``
+    of lane ``i``'s seed."""
+    best = 0
+    for row_t, row_valid in zip(np.asarray(t, np.int64),
+                                np.asarray(valid, bool)):
+        lanes = np.flatnonzero(row_valid)
+        if lanes.size:
+            suffix = np.minimum.accumulate(np.where(
+                row_valid, row_t, np.iinfo(np.int64).max)[::-1])[::-1]
+            h = np.searchsorted(suffix, row_t[lanes] + span,
+                                side="right") - 1
+            best = max(best, int((h - lanes).max()) + 1)
+    return best
+
+
+def _visited_slots(layout, zone_chunk, span):
+    """``(padded, real)`` candidate-steps of the per-bucket scans: each
+    chunk of ``chunk`` zone rows (a bucket of more zones than the chunk is
+    padded to whole chunks) runs its own horizon trips over ``e`` lanes;
+    ``real`` leaves the padding rows out."""
+    padded = real = 0
+    for b in layout.buckets:
+        chunk = zone_chunk if zone_chunk and zone_chunk < b.n_zones \
+            else b.n_zones
+        for lo in range(0, b.n_zones, chunk):
+            trips = _horizon_trips(b.t[lo:lo + chunk],
+                                   b.valid[lo:lo + chunk], span)
+            padded += chunk * b.e_cap * trips
+            real += min(chunk, b.n_zones - lo) * b.e_cap * trips
+    return padded, real
+
+
 @pytest.mark.parametrize("zone_chunk", [None, 3])
 def test_per_bucket_sweep_slots_are_padded_zones_by_e_squared(zone_chunk):
+    """The counter reads the candidate-steps the scans ran: padded zone
+    rows by ``e`` by each scan's horizon trips, at most ``e`` (the dense
+    ``e**2`` per row)."""
     layout = _bucketed_layout()
     ex = MiningExecutor(delta=60, l_max=3, zone_chunk=zone_chunk,
                         fused="off")
     _, stats = ex.run_layout(layout)
-    chunk = zone_chunk or 1
-    # a bucket of more zones than the chunk is padded to whole chunks
-    rows = [-(-b.n_zones // chunk) * chunk if b.n_zones > chunk
-            else b.n_zones for b in layout.buckets]
-    want = sum(z * b.e_cap ** 2 for z, b in zip(rows, layout.buckets))
+    want, real = _visited_slots(layout, zone_chunk, 60 * 3)
     assert stats["path"] == "per-bucket"
-    assert stats["sweep_slots"] == ex.bucket_sweep_slots(layout) == want
+    assert stats["sweep_slots"] == want
+    assert want < sum(z * e * e for z, e in layout.bucket_shapes())
     if zone_chunk:
-        assert want > layout.sweep_slots       # padding rows are swept
+        assert want > real                     # padding rows are swept
+    else:
+        assert want == layout.sweep_slots
+
+
+def test_per_bucket_sweep_stops_at_the_horizon_in_wide_zones():
+    """Zones eight horizons wide: a bucket's scan runs far fewer trips
+    than its ``e`` slots, and the counter says so."""
+    layout = _bucketed_layout(omega=8)
+    ex = MiningExecutor(delta=60, l_max=3, fused="off")
+    _, stats = ex.run_layout(layout)
+    trips = [_horizon_trips(b.t, b.valid, 180) for b in layout.buckets]
+    assert any(2 * n < b.e_cap for n, b in zip(trips, layout.buckets))
+    assert stats["sweep_slots"] == layout.sweep_slots == sum(
+        b.n_zones * b.e_cap * n for n, b in zip(trips, layout.buckets))
+    assert _counts_dict(ex.run_layout(layout).counts) == \
+        _counts_dict(MiningExecutor(delta=60, l_max=3, backend="numpy",
+                                    fused="off").run_layout(layout).counts)
 
 
 def test_fused_sweep_slots_are_the_block_descriptors_sum():
