@@ -12,11 +12,11 @@ Measures, on real zone batches (not ShapeDtypeStructs):
 3. measured **unique-code populations** per device-shard, validating the
    hierarchical-merge out_cap used in the dry-run variants;
 4. **hierarchical chunked aggregation** (core/executor agg modes): measured
-   throughput of legacy whole-batch vs hierarchical fold vs the pipelined
-   runner on one batch, plus the planner's peak-memory model showing the
-   zone-count ceiling move — at a fixed budget the legacy O(Z*C) flatten
-   caps Z, while the hierarchical fold's peak is Z-independent, and the
-   benchmark *runs* the fold at a zone count beyond the legacy cap;
+   throughput of legacy whole-batch vs hierarchical fold on one batch,
+   plus the planner's peak-memory model showing the zone-count ceiling
+   move — at a fixed budget the legacy O(Z*C) flatten caps Z, while the
+   hierarchical fold's peak is Z-independent, and the benchmark *runs*
+   the fold at a zone count beyond the legacy cap;
 5. **engine compiled-plan reuse** (core/engine): cold vs warm
    ``PTMTEngine.discover`` on the same-shaped workload.  The warm call must
    register a compile-cache hit and be measurably faster — this is the
@@ -100,7 +100,7 @@ def _legacy_z_ceiling(budget_bytes, e_cap, l_max, zone_chunk) -> int:
 
 
 def _hierarchical_section(smoke: bool):
-    """Throughput of the three agg modes + the memory-ceiling move."""
+    """Throughput of the two agg folds + the memory-ceiling move."""
     n_edges = 4_000 if smoke else 24_000
     g = sg.poisson_stream(n_edges, 300, rate=0.5, seed=7)
     # small-omega, e_cap-split zones: many modest zones, the regime where
@@ -114,7 +114,7 @@ def _hierarchical_section(smoke: bool):
 
     modes = {}
     counts_seen = {}
-    for agg in ("legacy", "hierarchical", "pipelined"):
+    for agg in ("legacy", "hierarchical"):
         ex = MiningExecutor(delta=DELTA, l_max=L_MAX, zone_chunk=zc, agg=agg)
         run = lambda: transitions.device_counts_to_dict(ex.run(batch))
         counts, secs = timed(run, warmup=1, repeats=1 if smoke else 2)
@@ -123,8 +123,8 @@ def _hierarchical_section(smoke: bool):
             "seconds": secs,
             "edges_per_s": g.n_edges / secs if secs else 0.0,
         }
-    assert counts_seen["hierarchical"] == counts_seen["legacy"] \
-        == counts_seen["pipelined"], "agg modes disagree — differential bug"
+    assert counts_seen["hierarchical"] == counts_seen["legacy"], \
+        "agg modes disagree — differential bug"
 
     merge_cap = planner.default_merge_cap(zc, batch.e_cap)
     hier_peak = planner.hierarchical_peak_bytes(

@@ -50,8 +50,8 @@ _CLI_HELP = {
     "backend": "zone-scan backend",
     "zone_chunk": "process zones in chunks of this many to bound memory "
                   "(explicit value beats --memory-budget-mb)",
-    "agg": "Phase-2 aggregation: hierarchical/pipelined bound peak memory "
-           "to O(zone_chunk) instead of O(zones)",
+    "agg": "Phase-2 aggregation: hierarchical bounds peak memory to "
+           "O(zone_chunk) instead of O(zones)",
     "merge_cap": "hierarchical bounded-merge carry width (default: derived)",
     "memory_budget_mb": "derive zone_chunk/merge_cap from this device "
                         "memory budget (core.planner) instead of hints",

@@ -217,6 +217,26 @@ class PTMTEngine:
                                            n_shards=n_shards)
         return plan, layout
 
+    def _note_run(self, keys, layout: tzp.ZoneBatchLayout,
+                  run_stats: dict) -> None:
+        """Account one layout run: its executions, launches, sweep slots
+        and layout.
+
+        A fused run is one launch of one executable, so the whole layout
+        resolves to a single key ("fused", or "fused_<backend>" when
+        dispatch rerouted the kernel, e.g. "fused_xla" on CPU); otherwise
+        each bucket has its own.
+        """
+        if str(run_stats.get("path", "")).startswith("fused"):
+            self._note_execution(keys[0], layout.n_zones)
+            self.stats.fused_runs += 1
+        else:
+            for key, bucket in zip(keys, layout.buckets):
+                self._note_execution(key, bucket.n_zones)
+        self.stats.launches += int(run_stats.get("launches", 0))
+        self.stats.sweep_slots += int(run_stats.get("sweep_slots", 0))
+        self._note_layout(layout)
+
     def _note_layout(self, layout: tzp.ZoneBatchLayout) -> None:
         self.stats.padding_ratio = layout.padding_ratio
         self.stats.bucket_occupancy = {
@@ -247,18 +267,7 @@ class PTMTEngine:
                 counts, run_stats = self.executor.run_layout(
                     layout, allow_overflow=self.config.allow_overflow)
                 sp.set(n_zones=plan.n_zones, path=run_stats.get("path"))
-            if str(run_stats.get("path", "")).startswith("fused"):
-                # one launch, one executable: the whole layout resolves to
-                # a single fused execution key ("fused" or "fused_<backend>"
-                # when dispatch rerouted the kernel, e.g. "fused_xla" on CPU)
-                self._note_execution(keys[0], layout.n_zones)
-                self.stats.fused_runs += 1
-            else:
-                for key, bucket in zip(keys, layout.buckets):
-                    self._note_execution(key, bucket.n_zones)
-            self.stats.launches += int(run_stats.get("launches", 0))
-            self.stats.sweep_slots += int(run_stats.get("sweep_slots", 0))
-            self._note_layout(layout)
+            self._note_run(keys, layout, run_stats)
             with tracer.span("engine.d2h", rows=int(counts.counts.shape[0])):
                 counts = jax.device_get(counts)
             with tracer.span("engine.decode") as sp:
@@ -332,15 +341,7 @@ class PTMTEngine:
                      for k in ex.layout_execution_keys(layout))
         counts_tuple, run_stats = ex.run_layout_multi(
             layout, params, allow_overflow=dom.allow_overflow)
-        if str(run_stats.get("path", "")).startswith("fused"):
-            self._note_execution(keys[0], layout.n_zones)
-            self.stats.fused_runs += 1
-        else:
-            for key, bucket in zip(keys, layout.buckets):
-                self._note_execution(key, bucket.n_zones)
-        self.stats.launches += int(run_stats.get("launches", 0))
-        self.stats.sweep_slots += int(run_stats.get("sweep_slots", 0))
-        self._note_layout(layout)
+        self._note_run(keys, layout, run_stats)
         layout_summary = {**layout.summary(), "execution": dict(run_stats)}
         for member, idx, counts in zip(lat.members, lat.indices,
                                        counts_tuple):

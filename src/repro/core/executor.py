@@ -9,9 +9,13 @@ Every discovery entry point (batch ``discover``, the sequential baseline,
 * zone chunking (chunks of ``zone_chunk`` zones to bound peak memory) is
   implemented once, with an explicit policy for zone counts that do not
   divide ``zone_chunk`` — **pad** (default: append inert zero-sign rows) or
-  **raise** — never the silent remainder drop the pre-refactor
-  ``_mine_batch`` had;
-* Phase-2 aggregation has three modes (``agg``):
+  **raise** — never a silent remainder drop;
+* every run mines a config lattice (see :class:`repro.core.planner.
+  ConfigLattice`): ``params``, a tuple of member ``(delta_i, l_max_i)``
+  pairs, each dominated by the executor's ``(delta, l_max)``.  One config
+  is the one-member lattice; its scan asks for no absorption timestamps,
+  so it traces the same program a config-only executor would;
+* Phase-2 aggregation has two folds (``agg``):
 
   - ``"legacy"``      — materialize every chunk's candidate codes, then one
                         whole-batch flatten-and-sort (peak O(Z*C));
@@ -23,28 +27,22 @@ Every discovery entry point (batch ``discover``, the sequential baseline,
                         Peak memory is O(zone_chunk*C + merge_cap),
                         independent of the zone count.  Spills (more live
                         unique codes than ``merge_cap``) are detected
-                        exactly and retried host-side with a doubled cap,
-                        so results are always exact;
-  - ``"pipelined"``   — same fold, driven by a host loop that double-buffers
-                        chunk dispatch: the next zone-chunk's host->device
-                        transfer is issued while the current chunk computes,
-                        and the carry buffers are donated to the jitted step
-                        so XLA reuses them in place.
+                        exactly and retried host-side with a larger cap
+                        (:func:`_grow_cap`), so results are always exact;
   - ``"auto"`` (default) resolves to ``"hierarchical"`` when chunking is
     active and ``"legacy"`` otherwise (identical numerics either way —
-    enforced by ``tests/test_differential.py``);
+    enforced by ``tests/test_differential.py``).  A lattice of several
+    members always takes the bounded fold: N whole-batch tables would each
+    be as wide as the batch;
 
 * ``zone_chunk`` itself no longer has to be a hardcoded hint: pass
   ``memory_budget_mb`` and the executor derives the chunk (and
   ``merge_cap``) from the backend's memory model via
   :mod:`repro.core.planner`;
-* jit compilation is cached per ``(backend, delta, l_max, zone_chunk,
-  merge_cap, batch shape)`` via module-level jitted functions shared by
-  every executor instance;
+* the jitted programs are module-level, so their compile caches are
+  shared by every executor instance;
 * host-only backends (``jittable=False``, e.g. the NumPy oracle) run their
-  scan outside the jit boundary and only the signed aggregation is jitted —
-  including a chunked host loop so even the oracle honors the hierarchical
-  memory bound.
+  scan outside the jit boundary and only the signed aggregation is jitted.
 
 ``scan_aggregate``/``scan_aggregate_partial`` are the traceable cores
 (usable inside ``shard_map``); ``run`` is the host-level entry that applies
@@ -70,7 +68,7 @@ from .aggregation import CodeCounts
 from .tzp import (FUSED_BOUNDS, ZoneBatch, ZoneBatchLayout, concat_layout,
                   pad_zone_arrays)
 
-AGG_MODES = ("auto", "legacy", "hierarchical", "pipelined")
+AGG_MODES = ("auto", "legacy", "hierarchical")
 
 
 class RunOutcome(NamedTuple):
@@ -95,15 +93,18 @@ class MultiRunOutcome(NamedTuple):
 class _BucketRun(NamedTuple):
     """One bucket launch: its counts and what its scans visited.
 
-    ``trips`` (a device scalar, or an int from a host scan) sums the
-    launch's per-chunk scan trips; every chunk has ``rows`` zone rows of
-    ``e`` slots, so the candidate-steps are ``rows * e * trips``.
+    ``counts`` holds one table per lattice member.  ``trips`` (a device
+    scalar, or an int from a host scan) sums the launch's per-chunk scan
+    trips; every chunk has ``rows`` zone rows of ``e`` slots, so the
+    candidate-steps are ``rows * e * trips``.  ``retries`` counts its
+    merge-cap spill retries.
     """
 
-    counts: object         # CodeCounts, or a tuple of them when co-mined
+    counts: tuple
     trips: object
     rows: int
     e: int
+    retries: int
 
 
 def _sweep_slots(runs) -> int:
@@ -121,6 +122,38 @@ def _chunk_rows(z: int, zc: int) -> int:
 #: whenever the backend publishes a bucket-native flat kernel, "on"
 #: requires one (erroring otherwise), "off" keeps the per-bucket path.
 FUSED_MODES = ("auto", "on", "off")
+
+
+def _grow_cap(cap: int, n_spilled: int, ceiling: int) -> int:
+    """The merge cap to retry with after ``n_spilled`` codes spilled.
+
+    ``cap + n_spilled`` approximates the live-code population (a code cut
+    in several steps is counted per step, so it can only overshoot the
+    next guess); the cap at least doubles, is rounded up to a power of
+    two, and is clamped to ``ceiling``, a cap that provably cannot spill,
+    so every retry loop terminates.
+    """
+    need = max(2 * cap, cap + n_spilled, 8)
+    return min(1 << (need - 1).bit_length(), ceiling)
+
+
+def _grow_spilled(caps, spills, ceiling: int, *, what: str, path: str,
+                  obs) -> tuple:
+    """Grow the caps of the members that spilled; warn and count the retry
+    under the counter label ``path`` (``-multi`` for a co-mine, whose
+    warning lists every member's spill).  ``what`` names the merge."""
+    new = tuple(_grow_cap(c, n, ceiling) if n else c
+                for c, n in zip(caps, spills))
+    if path.endswith("-multi"):
+        msg = (f"{what} co-mine spilled {list(spills)} unique code(s) across "
+               f"{len(caps)} member config(s); retrying with "
+               f"merge_caps={list(new)}")
+    else:
+        msg = (f"{what} merge spilled {spills[0]} unique code(s) at "
+               f"merge_cap={caps[0]}; retrying with merge_cap={new[0]}")
+    warnings.warn(msg, RuntimeWarning, stacklevel=4)
+    obs.metrics.counter("repro_mining_spill_retries_total", path=path).inc()
+    return new
 
 
 @functools.partial(jax.jit, static_argnames=("cap",))
@@ -145,9 +178,9 @@ def merge_partial_counts(
     concat-and-sort, so the resident merge state is O(cap) regardless of
     how many buckets a layout produced.  ``merge_cap`` seeds the carry
     width; a spill (more live unique codes than rows) is detected exactly
-    and retried with a doubled cap, capped at the provably-sufficient
-    ceiling (total live rows + 1 slot for the all-zero padding group), so
-    the result is always exact.
+    and retried with a larger cap (:func:`_grow_cap`), capped at the
+    provably-sufficient ceiling (total live rows + 1 slot for the all-zero
+    padding group), so the result is always exact.
     """
     obs = get_obs(obs)
     parts = list(parts)
@@ -170,16 +203,8 @@ def merge_partial_counts(
             if n_spilled == 0:
                 sp.set(merge_cap=cap).sync(carry)
                 return carry
-            need = max(2 * cap, cap + n_spilled, 8)
-            new_cap = min(1 << (need - 1).bit_length(), ceiling)
-            warnings.warn(
-                f"{warn_label} merge spilled {n_spilled} unique code(s) at "
-                f"merge_cap={cap}; retrying with merge_cap={new_cap}",
-                RuntimeWarning, stacklevel=3,
-            )
-            obs.metrics.counter("repro_mining_spill_retries_total",
-                                path="fold").inc()
-            cap = new_cap
+            cap, = _grow_spilled((cap,), (n_spilled,), ceiling,
+                                 what=warn_label, path="fold", obs=obs)
 
 
 class ZoneChunkError(ValueError):
@@ -203,33 +228,48 @@ def _trips(res, e: int):
     return e if res.trips is None else res.trips
 
 
-def _chunked_scan(scan, u, v, t, valid, *, delta, l_max, zone_chunk):
-    """Sweep a [Z, E] zone batch, optionally in chunks of ``zone_chunk``.
+def _sweep(scan, *args, params, delta, l_max, **kw):
+    """Run the dominating ``(delta, l_max)`` scan for the members in
+    ``params``.  It is asked for per-step absorption timestamps only when
+    some member is smaller, so a one-member lattice traces the plain scan
+    and runs on backends without co-mine support too."""
+    if any(p != (delta, l_max) for p in params):
+        kw["with_ts"] = True
+    return scan(*args, delta=delta, l_max=l_max, **kw)
 
-    Traceable; shapes are static here, so divisibility is checked at trace
-    time (the executor's host path pads beforehand under pad_policy='pad').
-    Returns ``(codes, lengths, trips)``, ``trips`` summed over the chunks.
+
+def _member_views(code, length, ts, *, params, delta, l_max):
+    """Every member's ``(code, length)`` view of dominating sweep output,
+    aligned with ``params``.
+
+    The dominating member is the sweep itself; every smaller ``(delta,
+    l_max)`` is the timestamp-gap prefix truncation
+    (:func:`repro.core.expansion.derive_lengths` +
+    :func:`repro.core.encoding.truncate_codes`) — lossless because zone
+    streams are time-sorted, so prefix processes of the dominating sweep
+    are exactly what the smaller config would have mined.
     """
+    views = []
+    for d_i, l_i in params:
+        if (d_i, l_i) == (delta, l_max):
+            views.append((code, length))
+        else:
+            len_i = expansion.derive_lengths(length, ts, delta=d_i, l_max=l_i)
+            views.append((encoding.truncate_codes(code, len_i), len_i))
+    return views
 
-    def chunk_fn(args):
-        cu, cv, ct, cvalid = args
-        with jax.named_scope("zone_scan"):
-            res = scan(cu, cv, ct, cvalid, delta=delta, l_max=l_max)
-        return res.code, res.length, jnp.int32(_trips(res, u.shape[1]))
 
-    z = u.shape[0]
-    if zone_chunk and zone_chunk < z:
-        nchunk = _n_chunks(z, zone_chunk)
-        reshape = lambda x: x.reshape(nchunk, zone_chunk, *x.shape[1:])
-        codes, lengths, trips = jax.lax.map(
-            chunk_fn, (reshape(u), reshape(v), reshape(t), reshape(valid))
-        )
-        codes = codes.reshape(z, *codes.shape[2:])
-        lengths = lengths.reshape(z, *lengths.shape[2:])
-        trips = trips.sum()
-    else:
-        codes, lengths, trips = chunk_fn((u, v, t, valid))
-    return codes, lengths, trips
+def _empty_members(merge_caps, limbs: int) -> tuple:
+    """Fresh ``(counts, spilled)`` carries, one per member cap."""
+    return tuple((aggregation.empty_counts(cap, limbs), jnp.int32(0))
+                 for cap in merge_caps)
+
+
+def _merge_member(member, part, cap: int):
+    """Fold one partial table into a member's ``(counts, spilled)``."""
+    counts, spilled = member
+    merged, spill = aggregation.merge_bounded(counts, part, cap=cap)
+    return merged, spilled + spill
 
 
 def _padded_zones(z: int, zone_chunk: int) -> int:
@@ -250,170 +290,50 @@ def _n_chunks(z: int, zone_chunk: int) -> int:
     return z // zone_chunk
 
 
-def _hier_fold(scan, u, v, t, valid, signs, *, delta, l_max, zone_chunk,
-               merge_cap):
-    """Hierarchical streaming aggregation (traceable).
-
-    Each zone-chunk is scanned and immediately signed-counted
-    (``aggregate_zones``); the partial tables fold through a bounded-width
-    carry (``merge_bounded``) inside ``lax.scan``, so at no point do all
-    Z*C candidate codes coexist.  Returns ``(CodeCounts[merge_cap],
-    spilled, trips)`` — ``spilled > 0`` means ``merge_cap`` was too small
-    and the result is inexact (the host retries with a doubled cap);
-    ``trips`` is the chunks' scan trips, summed.
-    """
-    z = u.shape[0]
-    zc = _chunk_rows(z, zone_chunk)
-    nchunk = _n_chunks(z, zc)
-    limbs = encoding.n_limbs(l_max)
-    reshape = lambda x: x.reshape(nchunk, zc, *x.shape[1:])
-    xs = (reshape(u), reshape(v), reshape(t), reshape(valid),
-          signs.reshape(nchunk, zc))
-
-    def body(carry, chunk):
-        counts, spilled, trips = carry
-        cu, cv, ct, cvalid, csigns = chunk
-        with jax.named_scope("zone_scan"):
-            res = scan(cu, cv, ct, cvalid, delta=delta, l_max=l_max)
-        with jax.named_scope("fold"):
-            part = aggregation.aggregate_zones(res.code, res.length, csigns)
-            merged, spill = aggregation.merge_bounded(counts, part,
-                                                      cap=merge_cap)
-        return (merged, spilled + spill,
-                trips + _trips(res, u.shape[1])), None
-
-    init = (aggregation.empty_counts(merge_cap, limbs), jnp.int32(0),
-            jnp.int32(0))
-    (counts, spilled, trips), _ = jax.lax.scan(body, init, xs)
-    return counts, spilled, trips
-
-
 @functools.partial(
-    jax.jit, static_argnames=("delta", "l_max", "scan", "zone_chunk")
+    jax.jit, static_argnames=("delta", "l_max", "scan", "zone_chunk",
+                              "params")
 )
-def _mine_jit(u, v, t, valid, signs, *, delta, l_max, scan, zone_chunk):
-    """Jitted legacy path: full zone sweep, then one whole-batch aggregation.
+def _mine_jit(u, v, t, valid, signs, *, delta, l_max, scan, zone_chunk,
+              params):
+    """Jitted legacy path: full zone sweep, then one whole-batch aggregation
+    per lattice member.
 
     jax.jit keys its cache on the static args plus input shapes, so every
-    executor instance with the same (scan fn, delta, l_max, zone_chunk,
-    batch shape) reuses one executable.  The cache is keyed on the resolved
-    scan *callable*, not the backend name, so re-registering a backend
-    (``overwrite=True``) cannot serve a stale executable.  Returns
-    ``(counts, trips)``.
+    executor instance with the same (scan fn, delta, l_max, params,
+    zone_chunk, batch shape) reuses one executable.  The cache is keyed on
+    the resolved scan *callable*, not the backend name, so re-registering
+    a backend (``overwrite=True``) cannot serve a stale executable.
+    Returns ``(*counts, trips)``: one table per member of ``params``, then
+    the trips, summed over the chunks.  Shapes are static here, so chunk
+    divisibility is checked at trace time (the host path pads beforehand
+    under pad_policy='pad').
     """
-    codes, lengths, trips = _chunked_scan(
-        scan, u, v, t, valid, delta=delta, l_max=l_max, zone_chunk=zone_chunk
-    )
+
+    def chunk_fn(args):
+        cu, cv, ct, cvalid = args
+        with jax.named_scope("zone_scan"):
+            res = _sweep(scan, cu, cv, ct, cvalid, params=params,
+                         delta=delta, l_max=l_max)
+        return (res.code, res.length, res.ts), \
+            jnp.int32(_trips(res, u.shape[1]))
+
+    z = u.shape[0]
+    if zone_chunk and zone_chunk < z:
+        nchunk = _n_chunks(z, zone_chunk)
+        reshape = lambda x: x.reshape(nchunk, zone_chunk, *x.shape[1:])
+        out, trips = jax.lax.map(
+            chunk_fn, (reshape(u), reshape(v), reshape(t), reshape(valid)))
+        out = jax.tree.map(lambda x: x.reshape(z, *x.shape[2:]), out)
+        trips = trips.sum()
+    else:
+        out, trips = chunk_fn((u, v, t, valid))
+    codes, lengths, ts = out
     with jax.named_scope("fold"):
-        return aggregation.aggregate_zones(codes, lengths, signs), trips
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("delta", "l_max", "scan", "zone_chunk", "merge_cap"),
-)
-def _mine_jit_hier(u, v, t, valid, signs, *, delta, l_max, scan, zone_chunk,
-                   merge_cap):
-    """Jitted hierarchical fold (shared compile cache, as ``_mine_jit``)."""
-    return _hier_fold(scan, u, v, t, valid, signs, delta=delta, l_max=l_max,
-                      zone_chunk=zone_chunk, merge_cap=merge_cap)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("delta", "l_max", "scan", "merge_cap"),
-    donate_argnums=(0, 1, 2),
-)
-def _pipeline_step(carry, spilled, trips, u, v, t, valid, signs, *, delta,
-                   l_max, scan, merge_cap):
-    """One pipelined chunk: scan + partial count + bounded merge.
-
-    The carry (and the spill and trip counters) are donated — XLA reuses
-    their buffers in place, so the resident aggregation state stays a
-    single ``merge_cap`` table no matter how many chunks stream through.
-    """
-    with jax.named_scope("zone_scan"):
-        res = scan(u, v, t, valid, delta=delta, l_max=l_max)
-    with jax.named_scope("fold"):
-        part = aggregation.aggregate_zones(res.code, res.length, signs)
-        merged, spill = aggregation.merge_bounded(carry, part,
-                                                  cap=merge_cap)
-    return merged, spilled + spill, trips + _trips(res, u.shape[1])
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("delta", "l_max", "scan", "blk", "fold_chunk",
-                     "merge_cap"),
-)
-def _mine_fused_jit(u, v, t, valid, zone_id, sign, lo, hi, *, delta, l_max,
-                    scan, blk, fold_chunk, merge_cap):
-    """Jitted fused path: single-launch flat scan + on-device Phase-2 fold.
-
-    One executable does the whole mine: the bucket-native kernel sweeps
-    every zone of the concatenated layout in a single launch, and the
-    candidate codes fold straight through ``count_codes`` +
-    ``merge_bounded`` in ``fold_chunk``-row slices inside the same jit —
-    only the bounded ``CodeCounts`` table and the spill counter leave the
-    device.  The [S, L] code block never round-trips to host.  ``scan``
-    is a static arg, so the Pallas and XLA lowerings compile separately.
-    """
-    with jax.named_scope("zone_scan"):
-        code, length = scan(u, v, t, valid, zone_id, lo, hi,
-                            delta=delta, l_max=l_max, blk=blk)
-    s, limbs = code.shape
-    nchunk = s // fold_chunk
-
-    def body(carry, chunk):
-        counts, spilled = carry
-        chunk_codes, chunk_w = chunk
-        part = aggregation.count_codes(chunk_codes, chunk_w)
-        merged, spill = aggregation.merge_bounded(counts, part,
-                                                  cap=merge_cap)
-        return (merged, spilled + spill), None
-
-    init = (aggregation.empty_counts(merge_cap, limbs), jnp.int32(0))
-    with jax.named_scope("fold"):
-        w = (length > 0).astype(jnp.int32) * sign
-        codes = jnp.where(w[:, None] != 0, code, 0)
-        xs = (codes.reshape(nchunk, fold_chunk, limbs),
-              w.reshape(nchunk, fold_chunk))
-        (counts, spilled), _ = jax.lax.scan(body, init, xs)
-    return counts, spilled
-
-
-@functools.partial(
-    jax.jit, static_argnames=("merge_cap",), donate_argnums=(0, 1)
-)
-def _merge_chunk_jit(carry, spilled, codes, lengths, signs, *, merge_cap):
-    """Bounded merge of one host-scanned chunk (host-only backends)."""
-    with jax.named_scope("fold"):
-        part = aggregation.aggregate_zones(codes, lengths, signs)
-        merged, spill = aggregation.merge_bounded(carry, part,
-                                                  cap=merge_cap)
-    return merged, spilled + spill
-
-
-# ---------------------------------------------------------------------------
-# Config-lattice co-mining: derive every member config's Phase-2 tables from
-# ONE dominating Phase-1 sweep (see planner.ConfigLattice).
-# ---------------------------------------------------------------------------
-
-
-def _derive_member(code, length, ts, *, d_i, l_i, delta, l_max):
-    """A member config's (code, length) view of dominating sweep output.
-
-    The dominating member is the sweep itself; every smaller ``(delta,
-    l_max)`` is the timestamp-gap prefix truncation
-    (:func:`repro.core.expansion.derive_lengths` +
-    :func:`repro.core.encoding.truncate_codes`) — lossless because zone
-    streams are time-sorted, so prefix processes of the dominating sweep
-    are exactly what the smaller config would have mined.
-    """
-    if (d_i, l_i) == (delta, l_max):
-        return code, length
-    len_i = expansion.derive_lengths(length, ts, delta=d_i, l_max=l_i)
-    return encoding.truncate_codes(code, len_i), len_i
+        views = _member_views(codes, lengths, ts, params=params, delta=delta,
+                              l_max=l_max)
+        return (*(aggregation.aggregate_zones(c, n, signs)
+                  for c, n in views), trips)
 
 
 @functools.partial(
@@ -421,15 +341,20 @@ def _derive_member(code, length, ts, *, d_i, l_i, delta, l_max):
     static_argnames=("delta", "l_max", "scan", "zone_chunk", "params",
                      "merge_caps"),
 )
-def _mine_multi_jit(u, v, t, valid, signs, *, delta, l_max, scan, zone_chunk,
-                    params, merge_caps):
-    """Jitted multi-config hierarchical fold over a [Z, E] zone batch.
+def _mine_jit_hier(u, v, t, valid, signs, *, delta, l_max, scan, zone_chunk,
+                   params, merge_caps):
+    """Jitted hierarchical streaming aggregation (shared compile cache, as
+    ``_mine_jit``).
 
-    ONE ``with_ts`` dominating scan per chunk; each member of ``params``
-    (a tuple of ``(delta_i, l_max_i)``) folds its derived candidate view
-    through its own bounded merge carry.  Returns a tuple of
-    ``(CodeCounts, spilled)`` pairs aligned with ``params``, and the
-    chunks' scan trips, summed.
+    Each zone-chunk is scanned once at the dominating ``(delta, l_max)``
+    and, for every member of ``params``, immediately signed-counted
+    (``aggregate_zones``) from its derived view; the partial tables fold
+    through bounded-width carries (``merge_bounded``, one per member cap
+    in ``merge_caps``) inside ``lax.scan``, so at no point do all Z*C
+    candidate codes coexist.  Returns ``(members, trips)``: one
+    ``(CodeCounts[cap], spilled)`` per member — ``spilled > 0`` means its
+    cap was too small and its table inexact (the host retries with a
+    larger cap) — and the chunks' scan trips, summed.
     """
     z = u.shape[0]
     zc = _chunk_rows(z, zone_chunk)
@@ -443,26 +368,20 @@ def _mine_multi_jit(u, v, t, valid, signs, *, delta, l_max, scan, zone_chunk,
         members, trips = carry
         cu, cv, ct, cvalid, csigns = chunk
         with jax.named_scope("zone_scan"):
-            res = scan(cu, cv, ct, cvalid, delta=delta, l_max=l_max,
-                       with_ts=True)
-        new_carry = []
+            res = _sweep(scan, cu, cv, ct, cvalid, params=params,
+                         delta=delta, l_max=l_max)
         with jax.named_scope("fold"):
-            for (d_i, l_i), (counts, spilled), cap in zip(params, members,
-                                                          merge_caps):
-                code_i, len_i = _derive_member(
-                    res.code, res.length, res.ts,
-                    d_i=d_i, l_i=l_i, delta=delta, l_max=l_max)
-                part = aggregation.aggregate_zones(code_i, len_i, csigns)
-                merged, spill = aggregation.merge_bounded(counts, part,
-                                                          cap=cap)
-                new_carry.append((merged, spilled + spill))
-        return (tuple(new_carry), trips + _trips(res, u.shape[1])), None
+            views = _member_views(res.code, res.length, res.ts,
+                                  params=params, delta=delta, l_max=l_max)
+            members = tuple(
+                _merge_member(m, aggregation.aggregate_zones(c, n, csigns),
+                              cap)
+                for m, (c, n), cap in zip(members, views, merge_caps))
+        return (members, trips + _trips(res, u.shape[1])), None
 
-    init = tuple(
-        (aggregation.empty_counts(cap, limbs), jnp.int32(0))
-        for cap in merge_caps)
-    out, _ = jax.lax.scan(body, (init, jnp.int32(0)), xs)
-    return out
+    init = (_empty_members(merge_caps, limbs), jnp.int32(0))
+    (members, trips), _ = jax.lax.scan(body, init, xs)
+    return members, trips
 
 
 @functools.partial(
@@ -470,63 +389,60 @@ def _mine_multi_jit(u, v, t, valid, signs, *, delta, l_max, scan, zone_chunk,
     static_argnames=("delta", "l_max", "scan", "blk", "fold_chunk",
                      "params", "merge_caps"),
 )
-def _mine_fused_multi_jit(u, v, t, valid, zone_id, sign, lo, hi, *, delta,
-                          l_max, scan, blk, fold_chunk, params, merge_caps):
-    """Jitted fused co-mine: ONE flat kernel launch, N on-device folds.
+def _mine_fused_jit(u, v, t, valid, zone_id, sign, lo, hi, *, delta, l_max,
+                    scan, blk, fold_chunk, params, merge_caps):
+    """Jitted fused path: single-launch flat scan + on-device Phase-2 fold.
 
-    The single-launch analog of :func:`_mine_multi_jit`: the dominating
-    sweep runs once over the concatenated layout (with per-step absorption
-    timestamps), then every member config's derived candidate view streams
-    through its own ``count_codes`` + ``merge_bounded`` fold inside the
-    same executable.
+    One executable does the whole mine: the bucket-native kernel sweeps
+    every zone of the concatenated layout in a single launch, and each
+    member's candidate codes fold straight through ``count_codes`` +
+    ``merge_bounded`` in ``fold_chunk``-row slices inside the same jit —
+    only the bounded ``CodeCounts`` tables and their spill counters (one
+    pair per member) leave the device.  The [S, L] code block never
+    round-trips to host.  ``scan`` is a static arg, so the Pallas and XLA
+    lowerings compile separately.
     """
     with jax.named_scope("zone_scan"):
-        code, length, ts = scan(u, v, t, valid, zone_id, lo, hi,
-                                delta=delta, l_max=l_max, blk=blk,
-                                with_ts=True)
+        out = _sweep(scan, u, v, t, valid, zone_id, lo, hi, params=params,
+                     delta=delta, l_max=l_max, blk=blk)
+    code, length = out[:2]
     s, limbs = code.shape
     nchunk = s // fold_chunk
-    xs = (code.reshape(nchunk, fold_chunk, limbs),
-          length.reshape(nchunk, fold_chunk),
-          ts.reshape(nchunk, fold_chunk, ts.shape[-1]),
-          sign.reshape(nchunk, fold_chunk))
+    chunks = lambda x: x.reshape(nchunk, fold_chunk, *x.shape[1:])
+    xs = (chunks(code), chunks(length),
+          chunks(out[2]) if len(out) > 2 else None, chunks(sign))
 
-    def body(carry, chunk):
+    def body(members, chunk):
         c_code, c_len, c_ts, c_sign = chunk
-        new_carry = []
-        for (d_i, l_i), (counts, spilled), cap in zip(params, carry,
-                                                      merge_caps):
-            code_i, len_i = _derive_member(
-                c_code, c_len, c_ts,
-                d_i=d_i, l_i=l_i, delta=delta, l_max=l_max)
+        views = _member_views(c_code, c_len, c_ts, params=params,
+                              delta=delta, l_max=l_max)
+        new = []
+        for m, (code_i, len_i), cap in zip(members, views, merge_caps):
             w = (len_i > 0).astype(jnp.int32) * c_sign
-            codes_m = jnp.where(w[:, None] != 0, code_i, 0)
-            part = aggregation.count_codes(codes_m, w)
-            merged, spill = aggregation.merge_bounded(counts, part, cap=cap)
-            new_carry.append((merged, spilled + spill))
-        return tuple(new_carry), None
+            part = aggregation.count_codes(
+                jnp.where(w[:, None] != 0, code_i, 0), w)
+            new.append(_merge_member(m, part, cap))
+        return tuple(new), None
 
-    init = tuple(
-        (aggregation.empty_counts(cap, limbs), jnp.int32(0))
-        for cap in merge_caps)
     with jax.named_scope("fold"):
-        out, _ = jax.lax.scan(body, init, xs)
-    return out
+        members, _ = jax.lax.scan(body, _empty_members(merge_caps, limbs), xs)
+    return members
 
 
 @functools.partial(
-    jax.jit, static_argnames=("d_i", "l_i", "delta", "l_max", "merge_cap")
+    jax.jit, static_argnames=("delta", "l_max", "params", "merge_caps"),
+    donate_argnums=(0,)
 )
-def _derive_merge_chunk_jit(carry, spilled, codes, lengths, ts, signs, *,
-                            d_i, l_i, delta, l_max, merge_cap):
-    """One member config's bounded merge of a host-scanned chunk."""
+def _merge_chunk_jit(members, codes, lengths, ts, signs, *, delta, l_max,
+                     params, merge_caps):
+    """Bounded merge of one host-scanned chunk into every member's carry
+    (host-only backends); the carries are donated."""
     with jax.named_scope("fold"):
-        code_i, len_i = _derive_member(codes, lengths, ts, d_i=d_i,
-                                       l_i=l_i, delta=delta, l_max=l_max)
-        part = aggregation.aggregate_zones(code_i, len_i, signs)
-        merged, spill = aggregation.merge_bounded(carry, part,
-                                                  cap=merge_cap)
-    return merged, spilled + spill
+        views = _member_views(codes, lengths, ts, params=params, delta=delta,
+                              l_max=l_max)
+        return tuple(
+            _merge_member(m, aggregation.aggregate_zones(c, n, signs), cap)
+            for m, (c, n), cap in zip(members, views, merge_caps))
 
 
 class MiningExecutor:
@@ -539,9 +455,9 @@ class MiningExecutor:
         (None/0 = whole batch at once); defaults to the backend's hint.
       pad_policy: "pad" appends inert zero-sign zone rows when the zone
         count does not divide ``zone_chunk``; "raise" errors instead.
-      agg: Phase-2 aggregation mode — "auto", "legacy", "hierarchical" or
-        "pipelined" (see module docstring).
-      merge_cap: bounded-merge carry width for the hierarchical modes
+      agg: Phase-2 aggregation mode — "auto", "legacy" or "hierarchical"
+        (see module docstring).
+      merge_cap: bounded-merge carry width for the hierarchical fold
         (None = backend hint, else one chunk's candidate rows).  Spills
         are detected exactly and retried with a doubled cap.
       memory_budget_mb: derive ``zone_chunk``/``merge_cap`` from this
@@ -665,6 +581,11 @@ class MiningExecutor:
         return self.spec.name
 
     @property
+    def _params(self) -> tuple:
+        """This executor's own config as a one-member lattice."""
+        return ((self.delta, self.l_max),)
+
+    @property
     def last_run_stats(self) -> dict:
         """REMOVED — stats travel on each run's returned outcome."""
         raise RuntimeError(
@@ -759,12 +680,11 @@ class MiningExecutor:
         (static) zone count does not divide ``zone_chunk``.
         """
         self._require_jittable()
-        codes, lengths, _ = _chunked_scan(
-            self.spec.scan, u, v, t, valid,
-            delta=self.delta, l_max=self.l_max, zone_chunk=self.zone_chunk,
-        )
-        with jax.named_scope("fold"):
-            return aggregation.aggregate_zones(codes, lengths, signs)
+        counts, _ = _mine_jit(
+            u, v, t, valid, signs, delta=self.delta, l_max=self.l_max,
+            scan=self.spec.scan, zone_chunk=self.zone_chunk,
+            params=self._params)
+        return counts
 
     def scan_aggregate_partial(self, u, v, t, valid, signs):
         """Traceable scan+aggregate honoring the executor's ``agg`` mode.
@@ -780,12 +700,13 @@ class MiningExecutor:
         zc = self._zone_chunk_for(z, e)
         if self._agg_mode_for(zc, z) == "legacy":
             return self.scan_aggregate(u, v, t, valid, signs), jnp.int32(0)
-        counts, spilled, _ = _hier_fold(
-            self.spec.scan, u, v, t, valid, signs,
+        (member,), _ = _mine_jit_hier(
+            u, v, t, valid, signs, scan=self.spec.scan,
             delta=self.delta, l_max=self.l_max, zone_chunk=zc,
-            merge_cap=self._merge_cap_for(zc, z, e),
+            params=self._params,
+            merge_caps=(self._merge_cap_for(zc, z, e),),
         )
-        return counts, spilled
+        return member
 
     # -- host-level entry points -------------------------------------------
 
@@ -926,35 +847,94 @@ class MiningExecutor:
 
         Returns a :class:`RunOutcome` — the counts plus this run's own
         dispatch stats (never read stats back off the executor; that is
-        the shared-state race the outcome type exists to close).
+        the shared-state race the outcome type exists to close).  The
+        executor's own config runs as the one-member lattice of
+        :meth:`run_layout_multi`.
+        """
+        (counts,), stats = self._mine_layout(
+            layout, self._params, multi=False,
+            allow_overflow=allow_overflow, fused=fused)
+        return RunOutcome(counts=counts, stats=stats)
+
+    def _check_comine_params(self, params) -> tuple:
+        params = tuple((int(d), int(l)) for d, l in params)
+        if not params:
+            raise ValueError("co-mine needs at least one (delta, l_max)")
+        if not self.spec.supports_comine:
+            raise ValueError(
+                f"backend {self.backend!r} does not support co-mining "
+                f"(its scan has no with_ts timestamp output)")
+        for d, l in params:
+            if not (1 <= d <= self.delta and 1 <= l <= self.l_max):
+                raise ValueError(
+                    f"co-mined config (delta={d}, l_max={l}) is not "
+                    f"dominated by the sweep config (delta={self.delta}, "
+                    f"l_max={self.l_max})")
+        return params
+
+    def run_layout_multi(self, layout: ZoneBatchLayout, params, *,
+                         allow_overflow: bool = False,
+                         fused: bool | None = None) -> MultiRunOutcome:
+        """Co-mine N member configs from ONE dominating Phase-1 sweep.
+
+        ``params`` is a sequence of ``(delta_i, l_max_i)`` pairs, each
+        dominated by this executor's ``(delta, l_max)`` (the planner's
+        :func:`~repro.core.planner.build_config_lattices` guarantees that
+        for lattice members).  The layout is swept exactly once per launch
+        at the dominating config with per-step absorption timestamps; each
+        member's count table is split out during the Phase-2 fold by
+        prefix-truncating candidates on those timestamps — byte-identical
+        to mining that member independently, at one sweep's cost.
+
+        Returns a :class:`MultiRunOutcome` with one exact
+        :class:`CodeCounts` per param (spills retry per member with a
+        larger cap, exactly like the single-config paths).
+        """
+        params = self._check_comine_params(params)
+        return MultiRunOutcome(*self._mine_layout(
+            layout, params, multi=True, allow_overflow=allow_overflow,
+            fused=fused))
+
+    def _mine_layout(self, layout: ZoneBatchLayout, params, *, multi: bool,
+                     allow_overflow: bool, fused: bool | None):
+        """The one layout route: ``(counts tuple, stats)`` for ``params``.
+
+        ``multi`` marks a co-mine call, whose ``path`` labels carry a
+        ``-multi`` suffix and whose stats carry ``n_configs``.
         """
         if self.resolve_fused(fused):
-            return self.run_fused(layout, allow_overflow=allow_overflow)
+            return self._run_fused(layout, params, multi=multi,
+                                   allow_overflow=allow_overflow)
         self.check_layout_overflow(layout, allow_overflow=allow_overflow)
-        with self.obs.tracer.span("mine.layout", path="per-bucket",
-                                  buckets=layout.n_buckets):
+        suffix = "-multi" if multi else ""
+        path = "per-bucket" + suffix
+        configs = {"n_configs": len(params)} if multi else {}
+        with self.obs.tracer.span("mine.layout", path=path,
+                                  buckets=layout.n_buckets, **configs):
             runs = [
-                self._run_bucket(b.u, b.v, b.t, b.valid, b.sign,
-                                 label=b.label)
+                self._run_bucket(b.u, b.v, b.t, b.valid, b.sign, params,
+                                 suffix=suffix, label=b.label)
                 for b in layout.buckets
             ]
-            counts = merge_partial_counts([r.counts for r in runs],
-                                          merge_cap=self.merge_cap,
-                                          warn_label="zone-layout bucket",
-                                          obs=self.obs)
+            counts = tuple(
+                merge_partial_counts(list(p), merge_cap=self.merge_cap,
+                                     warn_label="zone-layout bucket",
+                                     obs=self.obs)
+                for p in zip(*(r.counts for r in runs)))
             stats = {
-                "path": "per-bucket",
+                "path": path,
                 "launches": len(layout.buckets),
-                "spill_retries": 0,
+                "spill_retries": sum(r.retries for r in runs),
                 "sweep_slots": _sweep_slots(runs),
+                **configs,
             }
             self.obs.metrics.counter(
                 "repro_mining_launches_total",
-                path="per-bucket").inc(len(layout.buckets))
+                path=path).inc(len(layout.buckets))
             self.obs.metrics.counter(
                 "repro_mining_sweep_slots_total",
-                path="per-bucket").inc(stats["sweep_slots"])
-            return RunOutcome(counts=counts, stats=stats)
+                path=path).inc(stats["sweep_slots"])
+            return counts, stats
 
     # -- fused single-launch path -------------------------------------------
 
@@ -1024,19 +1004,35 @@ class MiningExecutor:
         ``_mine_fused_jit`` — a single ``pallas_call`` whose grid spans
         every bucket, with the ``count_codes``/``merge_bounded`` fold in
         the same executable.  Only the bounded count table and the spill
-        counter come back; a spill retries host-side with a doubled cap
+        counter come back; a spill retries host-side with a larger cap
         (ceiling ``n_slots + 1``, which provably cannot spill).
         """
+        (counts,), stats = self._run_fused(
+            layout, self._params, multi=False, allow_overflow=allow_overflow)
+        return RunOutcome(counts=counts, stats=stats)
+
+    def run_fused_multi(self, layout: ZoneBatchLayout, params, *,
+                        allow_overflow: bool = False) -> MultiRunOutcome:
+        """Co-mine a layout in ONE kernel launch with N on-device folds."""
+        params = self._check_comine_params(params)
+        return MultiRunOutcome(*self._run_fused(
+            layout, params, multi=True, allow_overflow=allow_overflow))
+
+    def _run_fused(self, layout: ZoneBatchLayout, params, *, multi: bool,
+                   allow_overflow: bool):
+        """The one fused route: ``(counts tuple, stats)`` for ``params``."""
         self.check_layout_overflow(layout, allow_overflow=allow_overflow)
         obs = self.obs
         fspec = self._fused_spec()
-        path = self._fused_path()
+        suffix = "-multi" if multi else ""
+        path = self._fused_path(suffix)
         blk, fold_chunk, _ = self._fused_geometry(layout)
         fl = concat_layout(layout, blk=blk, pad_slots_to=fold_chunk,
                            delta=self.delta, l_max=self.l_max,
                            bounds=self.fused_bounds)
         cap_ceiling = fl.n_slots + 1
-        merge_cap = min(self._fused_merge_cap(fold_chunk), cap_ceiling)
+        caps = (min(self._fused_merge_cap(fold_chunk), cap_ceiling),) \
+            * len(params)
         with obs.tracer.span("mine.h2d", n_slots=fl.n_slots) as sp:
             arrays = tuple(jnp.asarray(x) for x in (
                 fl.u, fl.v, fl.t, fl.valid, fl.zone_id, fl.sign, fl.lo,
@@ -1044,53 +1040,45 @@ class MiningExecutor:
             sp.sync(arrays)
         retries = 0
         while True:
-            # one span per launch attempt: a spill retry at a doubled
+            # one span per launch attempt: a spill retry at a larger
             # merge_cap recompiles, and its jax.compile event lands here
             with obs.tracer.span("mine.fused", n_slots=fl.n_slots,
-                                 merge_cap=merge_cap, retry=retries) as sp:
-                counts, spilled = _mine_fused_jit(
+                                 merge_cap=max(caps), retry=retries) as sp:
+                out = _mine_fused_jit(
                     *arrays, delta=self.delta, l_max=self.l_max,
-                    scan=fspec.fused_scan, blk=blk,
-                    fold_chunk=fold_chunk, merge_cap=merge_cap,
+                    scan=fspec.fused_scan, blk=blk, fold_chunk=fold_chunk,
+                    params=params, merge_caps=caps,
                 )
-                sp.sync((counts, spilled))
+                sp.sync(out)
             with obs.tracer.span("mine.d2h"):
-                n_spilled = int(spilled)
-            if n_spilled == 0:
-                self._note_fused_cap(fold_chunk, merge_cap, retries)
-                stats = {
-                    "path": path,
-                    "backend": fspec.name,
-                    "bounds": fl.bounds,
-                    "launches": 1,
-                    "spill_retries": retries,
-                    "merge_cap": merge_cap,
-                    "fold_chunk": fold_chunk,
-                    "n_slots": fl.n_slots,
-                    "sweep_slots": fl.sweep_slots,
-                }
-                obs.metrics.counter("repro_mining_launches_total",
-                                    path=path).inc()
-                obs.metrics.counter("repro_mining_sweep_slots_total",
-                                    path=path).inc(fl.sweep_slots)
-                m = obs.metrics
-                m.gauge("repro_mining_fused_merge_cap").set(merge_cap)
-                m.gauge("repro_mining_fused_fold_chunk").set(fold_chunk)
-                m.gauge("repro_mining_fused_slots").set(fl.n_slots)
-                m.gauge("repro_mining_fused_sweep_slots").set(fl.sweep_slots)
-                return RunOutcome(counts=counts, stats=stats)
-            need = max(2 * merge_cap, merge_cap + n_spilled, 8)
-            new_cap = min(1 << (need - 1).bit_length(), cap_ceiling)
-            warnings.warn(
-                f"fused on-device merge spilled {n_spilled} unique code(s) "
-                f"at merge_cap={merge_cap}; retrying with "
-                f"merge_cap={new_cap}",
-                RuntimeWarning, stacklevel=3,
-            )
-            obs.metrics.counter("repro_mining_spill_retries_total",
-                                path="fused").inc()
-            merge_cap = new_cap
+                spills = [int(s) for _, s in out]
+            if not any(spills):
+                break
+            caps = _grow_spilled(caps, spills, cap_ceiling, obs=obs,
+                                 what="fused on-device", path="fused" + suffix)
             retries += 1
+        self._note_fused_cap(fold_chunk, max(caps), retries)
+        stats = {
+            "path": path,
+            "backend": fspec.name,
+            "bounds": fl.bounds,
+            "launches": 1,
+            "spill_retries": retries,
+            **({"merge_caps": caps, "n_configs": len(params)} if multi
+               else {"merge_cap": caps[0]}),
+            "fold_chunk": fold_chunk,
+            "n_slots": fl.n_slots,
+            "sweep_slots": fl.sweep_slots,
+        }
+        obs.metrics.counter("repro_mining_launches_total", path=path).inc()
+        obs.metrics.counter("repro_mining_sweep_slots_total",
+                            path=path).inc(fl.sweep_slots)
+        m = obs.metrics
+        m.gauge("repro_mining_fused_merge_cap").set(max(caps))
+        m.gauge("repro_mining_fused_fold_chunk").set(fold_chunk)
+        m.gauge("repro_mining_fused_slots").set(fl.n_slots)
+        m.gauge("repro_mining_fused_sweep_slots").set(fl.sweep_slots)
+        return tuple(c for c, _ in out), stats
 
     def layout_execution_keys(self, layout: ZoneBatchLayout,
                               fused: bool | None = None) -> tuple:
@@ -1107,258 +1095,25 @@ class MiningExecutor:
         return tuple(self.execution_key(b.n_zones, b.e_cap)
                      for b in layout.buckets)
 
-    # -- config-lattice co-mining --------------------------------------------
-
-    def _check_comine_params(self, params) -> tuple:
-        params = tuple((int(d), int(l)) for d, l in params)
-        if not params:
-            raise ValueError("co-mine needs at least one (delta, l_max)")
-        if not self.spec.supports_comine:
-            raise ValueError(
-                f"backend {self.backend!r} does not support co-mining "
-                f"(its scan has no with_ts timestamp output)")
-        for d, l in params:
-            if not (1 <= d <= self.delta and 1 <= l <= self.l_max):
-                raise ValueError(
-                    f"co-mined config (delta={d}, l_max={l}) is not "
-                    f"dominated by the sweep config (delta={self.delta}, "
-                    f"l_max={self.l_max})")
-        return params
-
-    def run_layout_multi(self, layout: ZoneBatchLayout, params, *,
-                         allow_overflow: bool = False,
-                         fused: bool | None = None) -> MultiRunOutcome:
-        """Co-mine N member configs from ONE dominating Phase-1 sweep.
-
-        ``params`` is a sequence of ``(delta_i, l_max_i)`` pairs, each
-        dominated by this executor's ``(delta, l_max)`` (the planner's
-        :func:`~repro.core.planner.build_config_lattices` guarantees that
-        for lattice members).  The layout is swept exactly once per launch
-        at the dominating config with per-step absorption timestamps; each
-        member's count table is split out during the Phase-2 fold by
-        prefix-truncating candidates on those timestamps — byte-identical
-        to mining that member independently, at one sweep's cost.
-
-        Returns a :class:`MultiRunOutcome` with one exact
-        :class:`CodeCounts` per param (spills retry per member with a
-        doubled cap, exactly like the single-config paths).
-        """
-        params = self._check_comine_params(params)
-        if self.resolve_fused(fused):
-            return self.run_fused_multi(layout, params,
-                                        allow_overflow=allow_overflow)
-        self.check_layout_overflow(layout, allow_overflow=allow_overflow)
-        with self.obs.tracer.span("mine.layout", path="per-bucket-multi",
-                                  buckets=layout.n_buckets,
-                                  n_configs=len(params)):
-            runs = []
-            retries_total = 0
-            for b in layout.buckets:
-                run, retries = self._run_arrays_multi(
-                    b.u, b.v, b.t, b.valid, b.sign, params, label=b.label)
-                runs.append(run)
-                retries_total += retries
-            counts = tuple(
-                merge_partial_counts(list(p), merge_cap=self.merge_cap,
-                                     warn_label="zone-layout bucket",
-                                     obs=self.obs)
-                for p in zip(*(r.counts for r in runs)))
-            sweep_slots = _sweep_slots(runs)
-            self.obs.metrics.counter(
-                "repro_mining_launches_total",
-                path="per-bucket-multi").inc(len(layout.buckets))
-            self.obs.metrics.counter(
-                "repro_mining_sweep_slots_total",
-                path="per-bucket-multi").inc(sweep_slots)
-            stats = {
-                "path": "per-bucket-multi",
-                "launches": len(layout.buckets),
-                "spill_retries": retries_total,
-                "sweep_slots": sweep_slots,
-                "n_configs": len(params),
-            }
-            return MultiRunOutcome(counts=counts, stats=stats)
-
-    def run_fused_multi(self, layout: ZoneBatchLayout, params, *,
-                        allow_overflow: bool = False) -> MultiRunOutcome:
-        """Co-mine a layout in ONE kernel launch with N on-device folds."""
-        params = self._check_comine_params(params)
-        self.check_layout_overflow(layout, allow_overflow=allow_overflow)
-        obs = self.obs
-        fspec = self._fused_spec()
-        path = self._fused_path("-multi")
-        blk, fold_chunk, _ = self._fused_geometry(layout)
-        fl = concat_layout(layout, blk=blk, pad_slots_to=fold_chunk,
-                           delta=self.delta, l_max=self.l_max,
-                           bounds=self.fused_bounds)
-        cap_ceiling = fl.n_slots + 1
-        caps = [min(self._fused_merge_cap(fold_chunk), cap_ceiling)
-                for _ in params]
-        with obs.tracer.span("mine.h2d", n_slots=fl.n_slots) as sp:
-            arrays = tuple(jnp.asarray(x) for x in (
-                fl.u, fl.v, fl.t, fl.valid, fl.zone_id, fl.sign, fl.lo,
-                fl.hi))
-            sp.sync(arrays)
-        retries = 0
-        while True:
-            with obs.tracer.span("mine.fused", n_slots=fl.n_slots,
-                                 n_configs=len(params), retry=retries) as sp:
-                out = _mine_fused_multi_jit(
-                    *arrays, delta=self.delta, l_max=self.l_max,
-                    scan=fspec.fused_scan, blk=blk,
-                    fold_chunk=fold_chunk, params=params,
-                    merge_caps=tuple(caps),
-                )
-                sp.sync(out)
-            with obs.tracer.span("mine.d2h"):
-                spills = [int(sp_i) for _, sp_i in out]
-            if not any(spills):
-                self._note_fused_cap(fold_chunk, max(caps), retries)
-                stats = {
-                    "path": path,
-                    "backend": fspec.name,
-                    "bounds": fl.bounds,
-                    "launches": 1,
-                    "spill_retries": retries,
-                    "merge_caps": tuple(caps),
-                    "fold_chunk": fold_chunk,
-                    "n_slots": fl.n_slots,
-                    "sweep_slots": fl.sweep_slots,
-                    "n_configs": len(params),
-                }
-                obs.metrics.counter("repro_mining_launches_total",
-                                    path=path).inc()
-                obs.metrics.counter("repro_mining_sweep_slots_total",
-                                    path=path).inc(fl.sweep_slots)
-                return MultiRunOutcome(
-                    counts=tuple(c for c, _ in out), stats=stats)
-            for i, n_spilled in enumerate(spills):
-                if n_spilled:
-                    need = max(2 * caps[i], caps[i] + n_spilled, 8)
-                    caps[i] = min(1 << (need - 1).bit_length(), cap_ceiling)
-            warnings.warn(
-                f"fused co-mine spilled {spills} unique code(s) across "
-                f"{len(params)} member config(s); retrying with "
-                f"merge_caps={caps}",
-                RuntimeWarning, stacklevel=3,
-            )
-            obs.metrics.counter("repro_mining_spill_retries_total",
-                                path="fused-multi").inc()
-            retries += 1
-
-    def _run_arrays_multi(self, u, v, t, valid, signs, params, *,
-                          label: str = ""):
-        """Co-mine raw [Z, E] zone arrays; returns ``(_BucketRun,
-        retries)``, the run's counts a tuple aligned with ``params``.
-
-        Mirrors :meth:`run_arrays`'s pad/chunk resolution, but always takes
-        the bounded hierarchical fold — the multi path has no legacy
-        whole-batch mode (an unchunked batch is simply one chunk).
-        """
-        u, v, t, valid, signs = (np.asarray(x)
-                                 for x in (u, v, t, valid, signs))
-        z, e = u.shape
-        with self.obs.tracer.span("mine.launch", z=z, e=e, label=label,
-                                  multi=len(params)) as sp:
-            zc = self._zone_chunk_for(z, e)
-            if zc and zc < z and z % zc != 0:
-                if self.pad_policy == "raise":
-                    where = f" in bucket {label!r}" if label else ""
-                    raise ZoneChunkError(
-                        f"zone count {z}{where} is not divisible by "
-                        f"zone_chunk {zc} (pad_policy='raise'); the "
-                        f"trailing {z % zc} zone(s) would need inert "
-                        f"padding rows — pad the batch (pad_policy='pad') "
-                        f"or pick a divisor"
-                    )
-                u, v, t, valid, signs = pad_zone_arrays(
-                    u, v, t, valid, signs, n_rows=_padded_zones(z, zc))
-                z = u.shape[0]
-            sp.set(zone_chunk=zc)
-            counts, trips, retries = self._run_bounded_multi(
-                u, v, t, valid, signs, zc, params)
-            return _BucketRun(counts, trips, _chunk_rows(z, zc), e), retries
-
-    def _run_bounded_multi(self, u, v, t, valid, signs, zc, params):
-        """Multi-config bounded fold with per-member spill/retry;
-        returns ``(counts tuple, trips, retries)``."""
-        z, e = u.shape
-        cap_ceiling = z * e + 1
-        base_cap = min(self._merge_cap_for(zc, z, e), cap_ceiling)
-        caps = [base_cap for _ in params]
-        retries = 0
-        while True:
-            if not self.spec.jittable:
-                out, trips = self._fold_host_scan_multi(
-                    u, v, t, valid, signs, zc, params, caps)
-            else:
-                out, trips = _mine_multi_jit(
-                    jnp.asarray(u), jnp.asarray(v), jnp.asarray(t),
-                    jnp.asarray(valid), jnp.asarray(signs),
-                    delta=self.delta, l_max=self.l_max, scan=self.spec.scan,
-                    zone_chunk=zc, params=params, merge_caps=tuple(caps),
-                )
-            spills = [int(sp) for _, sp in out]
-            if not any(spills):
-                return tuple(c for c, _ in out), trips, retries
-            for i, n_spilled in enumerate(spills):
-                if n_spilled:
-                    need = max(2 * caps[i], caps[i] + n_spilled, 8)
-                    caps[i] = min(1 << (need - 1).bit_length(), cap_ceiling)
-            warnings.warn(
-                f"co-mine hierarchical merge spilled {spills} unique "
-                f"code(s) across {len(params)} member config(s); retrying "
-                f"with merge_caps={caps}",
-                RuntimeWarning, stacklevel=3,
-            )
-            self.obs.metrics.counter("repro_mining_spill_retries_total",
-                                     path="bucket-multi").inc()
-            retries += 1
-
-    def _fold_host_scan_multi(self, u, v, t, valid, signs, zc, params, caps):
-        """Chunked multi-config fold for host-only backends; returns
-        ``(carries, trips)``."""
-        z, e = u.shape
-        zc = _chunk_rows(z, zc)
-        nchunk = _n_chunks(z, zc)
-        limbs = encoding.n_limbs(self.l_max)
-        carries = [
-            (aggregation.empty_counts(cap, limbs), jnp.int32(0))
-            for cap in caps]
-        trips = 0
-        for i in range(nchunk):
-            sl = slice(i * zc, (i + 1) * zc)
-            res = self.spec.scan(u[sl], v[sl], t[sl], valid[sl],
-                                 delta=self.delta, l_max=self.l_max,
-                                 with_ts=True)
-            trips += int(_trips(res, e))
-            codes = jnp.asarray(res.code)
-            lengths = jnp.asarray(res.length)
-            ts = jnp.asarray(res.ts)
-            sg = jnp.asarray(signs[sl])
-            for ci, ((d_i, l_i), cap) in enumerate(zip(params, caps)):
-                carry, spilled = carries[ci]
-                carries[ci] = _derive_merge_chunk_jit(
-                    carry, spilled, codes, lengths, ts, sg,
-                    d_i=d_i, l_i=l_i, delta=self.delta, l_max=self.l_max,
-                    merge_cap=cap,
-                )
-        return carries, trips
-
     def run_arrays(self, u, v, t, valid, signs, *,
                    label: str = "") -> CodeCounts:
         """Mine raw [Z, E] zone arrays (+ [Z] signs) to signed code counts."""
-        return self._run_bucket(u, v, t, valid, signs, label=label).counts
+        return self._run_bucket(u, v, t, valid, signs, self._params,
+                                label=label).counts[0]
 
-    def _run_bucket(self, u, v, t, valid, signs, *,
-                    label: str = "") -> _BucketRun:
-        """One bucket launch of :meth:`run_arrays`, with its scan trips.
+    def _run_bucket(self, u, v, t, valid, signs, params, *,
+                    suffix: str = "", label: str = "") -> _BucketRun:
+        """One bucket launch for every member of ``params``.
+
+        Pads the zone count to the chunk, then picks the fold: the
+        whole-batch fold for an unchunked one-member lattice under "auto",
+        else the bounded fold with its spill retry.
 
         Spans: ``mine.launch`` around the whole bucket; inside it, on the
-        jitted legacy and hierarchical paths, ``mine.bucket_h2d`` (the
-        arrays put on the device) and ``mine.bucket_scan`` (the jitted scan
-        and in-bucket fold up to its counts, one span per spill attempt).
-        Each is synced at its end only when the tracer is on.
+        jitted paths, ``mine.bucket_h2d`` (the arrays put on the device)
+        and ``mine.bucket_scan`` (the jitted scan and in-bucket fold up to
+        its counts, one span per spill attempt).  Each is synced at its
+        end only when the tracer is on.
         """
         u, v, t, valid, signs = (np.asarray(x)
                                  for x in (u, v, t, valid, signs))
@@ -1380,142 +1135,108 @@ class MiningExecutor:
                     u, v, t, valid, signs, n_rows=_padded_zones(z, zc))
                 z = u.shape[0]
 
-            mode = self._agg_mode_for(zc, z)
+            # a co-mine has no whole-batch fold: N tables each as wide as
+            # the bucket
+            mode = (self._agg_mode_for(zc, z) if len(params) == 1
+                    else "hierarchical")
             sp.set(agg=mode, zone_chunk=zc)
-            if self.spec.jittable and mode != "pipelined":
-                # the pipelined fold puts each chunk itself, behind the
-                # running one
+            if self.spec.jittable:
                 with tracer.span("mine.bucket_h2d") as h2d:
                     u, v, t, valid, signs = arrays = tuple(
                         jnp.asarray(x) for x in (u, v, t, valid, signs))
                     h2d.sync(arrays)
             if mode == "legacy":
-                counts, trips = self._run_legacy(u, v, t, valid, signs, zc)
+                counts, trips = self._run_legacy(u, v, t, valid, signs, zc,
+                                                 params)
+                retries = 0
             else:
-                counts, trips = self._run_bounded(u, v, t, valid, signs, zc,
-                                                  mode)
+                counts, trips, retries = self._run_bounded(
+                    u, v, t, valid, signs, zc, params, suffix)
             sp.sync(counts)
-            return _BucketRun(counts, trips, _chunk_rows(z, zc), e)
+            return _BucketRun(counts, trips, _chunk_rows(z, zc), e, retries)
 
-    def _run_legacy(self, u, v, t, valid, signs, zc):
-        """Whole-batch fold; returns ``(counts, trips)``."""
+    def _run_legacy(self, u, v, t, valid, signs, zc, params):
+        """Whole-batch fold; returns ``(counts tuple, trips)``."""
         if not self.spec.jittable:
-            res = self.spec.scan(u, v, t, valid,
-                                 delta=self.delta, l_max=self.l_max)
-            return aggregation.aggregate_zones(
-                jnp.asarray(res.code), jnp.asarray(res.length),
-                jnp.asarray(signs),
-            ), _trips(res, u.shape[1])
+            code, length, ts, trips = self._host_scan(u, v, t, valid, params)
+            views = _member_views(code, length, ts, params=params,
+                                  delta=self.delta, l_max=self.l_max)
+            return tuple(
+                aggregation.aggregate_zones(c, n, jnp.asarray(signs))
+                for c, n in views), trips
         with self.obs.tracer.span("mine.bucket_scan") as sp:
-            counts, trips = _mine_jit(
+            *counts, trips = _mine_jit(
                 u, v, t, valid, signs, delta=self.delta, l_max=self.l_max,
-                scan=self.spec.scan, zone_chunk=zc,
+                scan=self.spec.scan, zone_chunk=zc, params=params,
             )
             sp.sync(counts)
-        return counts, trips
+        return tuple(counts), trips
 
-    def _run_bounded(self, u, v, t, valid, signs, zc, mode):
-        """Hierarchical/pipelined fold with the merge-cap spill policy;
-        returns ``(counts, trips)``.
+    def _run_bounded(self, u, v, t, valid, signs, zc, params, suffix):
+        """Hierarchical fold with the per-member merge-cap spill policy;
+        returns ``(counts tuple, trips, retries)``.
 
-        Spills are exact signals, so retrying with a doubled cap is
-        lossless; ``merge_cap >= z*e + 1`` can never spill (at most z*e
-        distinct live codes, plus one row for the all-zero padding group
-        that sorts ahead of them), so the loop terminates.
+        Spills are exact signals, so retrying with a larger cap is
+        lossless; only the members that spilled grow their caps.
+        ``merge_cap >= z*e + 1`` can never spill (at most z*e distinct
+        live codes, plus one row for the all-zero padding group that sorts
+        ahead of them), so the loop terminates.
         """
         z, e = u.shape
         cap_ceiling = z * e + 1
-        merge_cap = min(self._merge_cap_for(zc, z, e), cap_ceiling)
+        caps = (min(self._merge_cap_for(zc, z, e), cap_ceiling),) \
+            * len(params)
+        retries = 0
         while True:
             if not self.spec.jittable:
-                counts, spilled, trips = self._fold_host_scan(
-                    u, v, t, valid, signs, zc, merge_cap)
-            elif mode == "pipelined":
-                counts, spilled, trips = self._fold_pipelined(
-                    u, v, t, valid, signs, zc, merge_cap)
+                out, trips = self._fold_host_scan(u, v, t, valid, signs, zc,
+                                                  params, caps)
             else:
                 # one span per attempt: a spill retry runs the scan again
                 with self.obs.tracer.span("mine.bucket_scan",
-                                          merge_cap=merge_cap) as sp:
-                    counts, spilled, trips = _mine_jit_hier(
+                                          merge_cap=max(caps)) as sp:
+                    out, trips = _mine_jit_hier(
                         u, v, t, valid, signs, delta=self.delta,
                         l_max=self.l_max, scan=self.spec.scan,
-                        zone_chunk=zc, merge_cap=merge_cap,
+                        zone_chunk=zc, params=params, merge_caps=caps,
                     )
-                    sp.sync((counts, spilled))
-            n_spilled = int(spilled)
-            if n_spilled == 0:
-                return counts, trips
-            # cap+spilled approximates the live-code population (a code cut
-            # in several steps is counted per step, so it can only
-            # overshoot the next guess); exactness is re-checked each
-            # round, and the z*e+1 ceiling provably cannot spill
-            need = max(2 * merge_cap, merge_cap + n_spilled, 8)
-            new_cap = min(1 << (need - 1).bit_length(), cap_ceiling)
-            warnings.warn(
-                f"hierarchical merge spilled {n_spilled} unique code(s) at "
-                f"merge_cap={merge_cap}; retrying with merge_cap={new_cap}",
-                RuntimeWarning, stacklevel=3,
-            )
-            self.obs.metrics.counter("repro_mining_spill_retries_total",
-                                     path="bucket").inc()
-            merge_cap = new_cap
+                    sp.sync(out)
+            spills = [int(s) for _, s in out]
+            if not any(spills):
+                return tuple(c for c, _ in out), trips, retries
+            caps = _grow_spilled(caps, spills, cap_ceiling, obs=self.obs,
+                                 what="hierarchical", path="bucket" + suffix)
+            retries += 1
 
-    def _fold_pipelined(self, u, v, t, valid, signs, zc, merge_cap):
-        """Host-driven double-buffered chunk pipeline.
+    def _host_scan(self, u, v, t, valid, params):
+        """A host-only backend's scan of one chunk, its outputs put on the
+        device; returns ``(code, length, ts, trips)``."""
+        res = _sweep(self.spec.scan, u, v, t, valid, params=params,
+                     delta=self.delta, l_max=self.l_max)
+        ts = None if res.ts is None else jnp.asarray(res.ts)
+        return (jnp.asarray(res.code), jnp.asarray(res.length), ts,
+                int(_trips(res, u.shape[1])))
 
-        Each jitted step is dispatched asynchronously; the *next* chunk's
-        host->device transfer (``jax.device_put``) is issued immediately
-        after, overlapping with the in-flight compute.  Carry buffers are
-        donated, so aggregation state never exceeds one ``merge_cap``
-        table.
-        """
-        z, e = u.shape
-        zc = _chunk_rows(z, zc)
-        nchunk = _n_chunks(z, zc)
-        limbs = encoding.n_limbs(self.l_max)
-
-        def put(i):
-            sl = slice(i * zc, (i + 1) * zc)
-            return tuple(jax.device_put(x[sl])
-                         for x in (u, v, t, valid, signs))
-
-        carry = aggregation.empty_counts(merge_cap, limbs)
-        spilled = jnp.zeros((), jnp.int32)
-        trips = jnp.zeros((), jnp.int32)
-        nxt = put(0)
-        for i in range(nchunk):
-            cur = nxt
-            carry, spilled, trips = _pipeline_step(
-                carry, spilled, trips, *cur, delta=self.delta,
-                l_max=self.l_max, scan=self.spec.scan, merge_cap=merge_cap,
-            )
-            if i + 1 < nchunk:
-                nxt = put(i + 1)    # async H2D behind the running chunk
-        return carry, spilled, trips
-
-    def _fold_host_scan(self, u, v, t, valid, signs, zc, merge_cap):
-        """Chunked fold for host-only backends (scan outside jit).
+    def _fold_host_scan(self, u, v, t, valid, signs, zc, params, caps):
+        """Chunked fold for host-only backends (scan outside jit); returns
+        ``(members, trips)``.
 
         Even the NumPy oracle gets the hierarchical memory bound: only one
         chunk's [zc, E, L] code block exists at a time, merged through the
-        same bounded carry as the device paths.
+        same bounded carries as the device paths.
         """
         z, e = u.shape
         zc = _chunk_rows(z, zc)
-        nchunk = _n_chunks(z, zc)
-        limbs = encoding.n_limbs(self.l_max)
-        carry = aggregation.empty_counts(merge_cap, limbs)
-        spilled = jnp.zeros((), jnp.int32)
+        members = _empty_members(caps, encoding.n_limbs(self.l_max))
         trips = 0
-        for i in range(nchunk):
+        for i in range(_n_chunks(z, zc)):
             sl = slice(i * zc, (i + 1) * zc)
-            res = self.spec.scan(u[sl], v[sl], t[sl], valid[sl],
-                                 delta=self.delta, l_max=self.l_max)
-            trips += int(_trips(res, e))
-            carry, spilled = _merge_chunk_jit(
-                carry, spilled, jnp.asarray(res.code),
-                jnp.asarray(res.length), jnp.asarray(signs[sl]),
-                merge_cap=merge_cap,
+            code, length, ts, n = self._host_scan(u[sl], v[sl], t[sl],
+                                                  valid[sl], params)
+            trips += n
+            members = _merge_chunk_jit(
+                members, code, length, ts, jnp.asarray(signs[sl]),
+                delta=self.delta, l_max=self.l_max, params=params,
+                merge_caps=caps,
             )
-        return carry, spilled, trips
+        return members, trips

@@ -141,13 +141,13 @@ def test_cli_round_trip_non_defaults():
     MiningConfig.add_cli_args(ap)
     args = ap.parse_args([
         "--delta", "45", "--l-max", "4", "--omega", "6", "--e-cap", "128",
-        "--backend", "numpy", "--zone-chunk", "2", "--agg", "pipelined",
+        "--backend", "numpy", "--zone-chunk", "2", "--agg", "hierarchical",
         "--merge-cap", "512", "--allow-overflow",
     ])
     cfg = MiningConfig.from_cli_args(args)
     assert cfg == MiningConfig(
         delta=45, l_max=4, omega=6, e_cap=128, backend="numpy",
-        zone_chunk=2, agg="pipelined", merge_cap=512, allow_overflow=True)
+        zone_chunk=2, agg="hierarchical", merge_cap=512, allow_overflow=True)
 
 
 def test_cli_rejects_bad_choices():
@@ -155,7 +155,9 @@ def test_cli_rejects_bad_choices():
     MiningConfig.add_cli_args(ap)
     with pytest.raises(SystemExit):
         ap.parse_args(["--agg", "bogus"])
-    assert set(AGG_MODES) >= {"auto", "legacy", "hierarchical", "pipelined"}
+    with pytest.raises(SystemExit):
+        ap.parse_args(["--agg", "pipelined"])
+    assert AGG_MODES == ("auto", "legacy", "hierarchical")
 
 
 # -- removed shims ----------------------------------------------------------

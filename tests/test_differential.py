@@ -6,7 +6,7 @@ build_zone_batch -> MiningExecutor.run`` pipeline for every backend
 interpret mode on CPU; the fused tests additionally sweep the compiled
 ``xla`` lowering against the interpreted Pallas one) and every
 aggregation configuration (chunked vs
-unchunked, legacy whole-batch vs hierarchical bounded-carry vs pipelined),
+unchunked, legacy whole-batch vs hierarchical bounded-carry),
 and all results must agree code-for-code — with the standalone oracle as
 ground truth whenever the batch is exact (``overflow == 0``).
 
@@ -99,9 +99,9 @@ def test_full_path_backends_agree_on_corpus(name, gen, params):
 
 
 @pytest.mark.parametrize("backend", BACKENDS)
-@pytest.mark.parametrize("agg", ["legacy", "hierarchical", "pipelined"])
+@pytest.mark.parametrize("agg", ["legacy", "hierarchical"])
 def test_chunked_agg_modes_match_unchunked(backend, agg):
-    """Chunked x {legacy, hierarchical, pipelined} == one-shot whole batch,
+    """Chunked x {legacy, hierarchical} == one-shot whole batch,
     for jittable and host-only backends alike."""
     g = _bursty(seed=3, n=140)
     delta, l_max = 20, 4
@@ -601,7 +601,7 @@ if hyp is not None:
         delta=st.integers(2, 8),
         l_max=st.integers(2, 4),
         zone_chunk=st.sampled_from([2, 3, 4]),
-        agg=st.sampled_from(["hierarchical", "pipelined"]),
+        agg=st.just("hierarchical"),
     )
     def test_fuzz_hierarchical_agg_matches_legacy(g, delta, l_max,
                                                   zone_chunk, agg):

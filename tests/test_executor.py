@@ -272,10 +272,11 @@ def test_mining_programs_name_the_scan_and_the_fold(program):
     if program == "hier":
         lowered = ex_mod._mine_jit_hier.lower(
             *arrays, delta=60, l_max=3, scan=scan, zone_chunk=2,
-            merge_cap=64)
+            params=((60, 3),), merge_caps=(64,))
     else:
         lowered = ex_mod._mine_jit.lower(
-            *arrays, delta=60, l_max=3, scan=scan, zone_chunk=2)
+            *arrays, delta=60, l_max=3, scan=scan, zone_chunk=2,
+            params=((60, 3),))
     scopes = _scopes(lowered.as_text(debug_info=True))
     assert {"zone_scan", "fold"} <= scopes
     assert "merge" not in scopes
@@ -447,3 +448,49 @@ def test_disabled_tracer_records_nothing_and_adds_no_sync(monkeypatch):
     names = [e["name"] for e in obs.tracer.events()]
     assert len(synced) >= names.count("mine.bucket_h2d") + \
         names.count("mine.bucket_scan") > 0
+
+
+# ---------------------------------------------------------------------------
+# One route for a single config and a lattice of them.
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("route", ["whole-batch", "bounded", "host-scan"])
+def test_single_config_scan_requests_no_timestamps(route):
+    """A single config is the one-member lattice and traces its own scan:
+    ``run_layout`` never asks for absorption timestamps, while a
+    two-member co-mine does, on every fold route."""
+    inner = get_backend("numpy" if route == "host-scan" else "ref").scan
+    asked = []
+
+    def spy(*args, **kw):
+        asked.append(kw.get("with_ts", False))
+        return inner(*args, **kw)
+
+    name = f"test-spy-{route}"
+    backends.register_backend(name, lambda: spy,
+                              jittable=route != "host-scan",
+                              supports_comine=True, overwrite=True)
+    try:
+        layout = _bucketed_layout()
+        ex = MiningExecutor(delta=60, l_max=3, backend=name, fused="off",
+                            zone_chunk=0 if route == "whole-batch" else 2)
+        want = ex.run_layout(layout).counts
+        assert asked and not any(asked)
+        asked.clear()
+        many = ex.run_layout_multi(layout, [(60, 3), (30, 2)]).counts
+        assert asked and all(asked)
+        assert _counts_dict(many[0]) == _counts_dict(want)
+    finally:
+        backends._REGISTRY.pop(name, None)
+
+
+@pytest.mark.parametrize("cap, n_spilled, ceiling, want", [
+    (64, 1, 10_000, 128),       # at least doubles
+    (64, 100, 10_000, 256),     # cap + n_spilled, up to a power of two
+    (64, 1, 100, 100),          # never past the cap that cannot spill
+])
+def test_grow_cap_after_a_spill(cap, n_spilled, ceiling, want):
+    from repro.core.executor import _grow_cap
+
+    assert _grow_cap(cap, n_spilled, ceiling) == want

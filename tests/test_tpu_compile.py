@@ -94,5 +94,6 @@ def test_fused_mine_with_device_fold_compiles_for_v5e(one_chip):
     compiled = executor._mine_fused_jit.lower(
         *[_i32(one_chip, SMOKE_SLOTS)] * 6, _i32(one_chip, n_blocks),
         _i32(one_chip, n_blocks), delta=DELTA, l_max=L_MAX, scan=scan,
-        blk=BLK, fold_chunk=4096, merge_cap=4096).compile()
+        blk=BLK, fold_chunk=4096, params=((DELTA, L_MAX),),
+        merge_caps=(4096,)).compile()
     assert _has_kernel(compiled)
