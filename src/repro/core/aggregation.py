@@ -30,6 +30,12 @@ class CodeCounts(NamedTuple):
     ``counts`` int32[N]   — aligned with codes;
     ``unique_mask`` bool[N].
     The all-zero padding code, if present, is masked out.
+
+    Invariant: every row where ``unique_mask`` is true lies in the first
+    ``unique_mask.sum() + 1`` rows.  :func:`count_codes` writes its groups
+    to the front (the +1 is the padding group), :func:`merge_bounded`
+    keeps a prefix of them, and :func:`empty_counts` has none; the
+    cross-bucket merge trims each part to that prefix before sorting.
     """
 
     codes: jax.Array

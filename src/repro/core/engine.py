@@ -62,7 +62,8 @@ class EngineStats:
     (``repro_mining_compile_cache_hits_total`` etc.; ``launches`` as
     ``repro_mining_launches_total`` by dispatch path, ``stream_launches``
     under ``path="stream"``, ``sweep_slots`` as
-    ``repro_mining_sweep_slots_total`` by dispatch path), so Prometheus
+    ``repro_mining_sweep_slots_total`` and ``merge_rows`` as
+    ``repro_mining_merge_rows_total`` by dispatch path), so Prometheus
     exports and ``EngineStats`` always agree."""
 
     discover_calls: int = 0
@@ -79,6 +80,7 @@ class EngineStats:
     launches: int = 0               # scan dispatches (fused layout run = 1)
     stream_launches: int = 0        # scan dispatches of engine.stream() miners
     sweep_slots: int = 0            # candidate-steps of the launched scans
+    merge_rows: int = 0             # rows the cross-bucket merges sorted
     fused_runs: int = 0             # discover calls served by the fused path
     padding_ratio: float = 0.0      # last layout's padded-slot waste
     bucket_occupancy: dict = dataclasses.field(default_factory=dict)
@@ -219,8 +221,8 @@ class PTMTEngine:
 
     def _note_run(self, keys, layout: tzp.ZoneBatchLayout,
                   run_stats: dict) -> None:
-        """Account one layout run: its executions, launches, sweep slots
-        and layout.
+        """Account one layout run: its executions, launches, sweep slots,
+        merge rows and layout.
 
         A fused run is one launch of one executable, so the whole layout
         resolves to a single key ("fused", or "fused_<backend>" when
@@ -235,6 +237,7 @@ class PTMTEngine:
                 self._note_execution(key, bucket.n_zones)
         self.stats.launches += int(run_stats.get("launches", 0))
         self.stats.sweep_slots += int(run_stats.get("sweep_slots", 0))
+        self.stats.merge_rows += int(run_stats.get("merge_rows", 0))
         self._note_layout(layout)
 
     def _note_layout(self, layout: tzp.ZoneBatchLayout) -> None:

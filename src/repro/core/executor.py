@@ -66,7 +66,7 @@ from repro.obs import get_obs
 from . import aggregation, backends, encoding, expansion, planner
 from .aggregation import CodeCounts
 from .tzp import (FUSED_BOUNDS, ZoneBatch, ZoneBatchLayout, concat_layout,
-                  pad_zone_arrays)
+                  next_pow2, pad_zone_arrays)
 
 AGG_MODES = ("auto", "legacy", "hierarchical")
 
@@ -156,12 +156,24 @@ def _grow_spilled(caps, spills, ceiling: int, *, what: str, path: str,
     return new
 
 
-@functools.partial(jax.jit, static_argnames=("cap",))
-def _merge_part_jit(carry, part, *, cap):
-    """One cross-bucket ``merge_bounded`` step, named ``merge`` in the
-    program (a scope opened around an eager call would not reach it)."""
+@functools.partial(jax.jit, static_argnames=("cap", "width"))
+def _merge_part_jit(carry, part, *, cap, width):
+    """One cross-bucket ``merge_bounded`` step over ``part``'s first
+    ``width`` rows, named ``merge`` in the program (a scope opened around
+    an eager call would not reach it).  The slice fuses into the merge's
+    concat, so the sort covers ``cap + width`` rows, not the whole part."""
     with jax.named_scope("merge"):
+        part = CodeCounts(*(x[:width] for x in part))
         return aggregation.merge_bounded(carry, part, cap=cap)
+
+
+class MergeOutcome(NamedTuple):
+    """A cross-bucket merge's table and the rows its sorts covered:
+    ``cap + width`` summed over every step of every spill attempt (0
+    when a single part needed no merge)."""
+
+    counts: CodeCounts
+    rows: int
 
 
 def merge_partial_counts(
@@ -170,7 +182,7 @@ def merge_partial_counts(
     merge_cap: int | None = None,
     warn_label: str = "partial",
     obs=None,
-) -> CodeCounts:
+) -> MergeOutcome:
     """Fold per-bucket (or per-shard) count tables through ``merge_bounded``.
 
     The cross-bucket analog of the hierarchical chunk fold: partial tables
@@ -181,28 +193,40 @@ def merge_partial_counts(
     and retried with a larger cap (:func:`_grow_cap`), capped at the
     provably-sufficient ceiling (total live rows + 1 slot for the all-zero
     padding group), so the result is always exact.
+
+    Each part enters its step trimmed to its live prefix: ``n`` unique
+    codes lie in its first ``n + 1`` rows (the :class:`CodeCounts`
+    invariant), so ``min(rows, next_pow2(n + 1))`` rows keep them all, and
+    the power of two bounds the step's compiled shapes.
     """
     obs = get_obs(obs)
     parts = list(parts)
     if not parts:
         raise ValueError("merge_partial_counts needs at least one table")
     if len(parts) == 1:
-        return parts[0]
+        return MergeOutcome(parts[0], 0)
     limbs = int(parts[0].codes.shape[1])
-    ceiling = sum(int(p.unique_mask.sum()) for p in parts) + 1
+    live = [int(n) for n in
+            jax.device_get([p.unique_mask.sum() for p in parts])]
+    widths = [min(int(p.unique_mask.shape[0]), next_pow2(n + 1))
+              for p, n in zip(parts, live)]
+    ceiling = sum(live) + 1
     cap = min(int(merge_cap), ceiling) if merge_cap else ceiling
     cap = max(cap, 8)
+    rows = 0
     with obs.tracer.span("mine.fold", parts=len(parts)) as sp:
         while True:
             carry = aggregation.empty_counts(cap, limbs)
             spilled = jnp.zeros((), jnp.int32)
-            for part in parts:
-                carry, spill = _merge_part_jit(carry, part, cap=cap)
+            for part, width in zip(parts, widths):
+                carry, spill = _merge_part_jit(carry, part, cap=cap,
+                                               width=width)
                 spilled = spilled + spill
+                rows += cap + width
             n_spilled = int(spilled)
             if n_spilled == 0:
-                sp.set(merge_cap=cap).sync(carry)
-                return carry
+                sp.set(merge_cap=cap, merge_rows=rows).sync(carry)
+                return MergeOutcome(carry, rows)
             cap, = _grow_spilled((cap,), (n_spilled,), ceiling,
                                  what=warn_label, path="fold", obs=obs)
 
@@ -916,25 +940,27 @@ class MiningExecutor:
                                  suffix=suffix, label=b.label)
                 for b in layout.buckets
             ]
-            counts = tuple(
+            merges = [
                 merge_partial_counts(list(p), merge_cap=self.merge_cap,
                                      warn_label="zone-layout bucket",
                                      obs=self.obs)
-                for p in zip(*(r.counts for r in runs)))
+                for p in zip(*(r.counts for r in runs))]
             stats = {
                 "path": path,
                 "launches": len(layout.buckets),
                 "spill_retries": sum(r.retries for r in runs),
                 "sweep_slots": _sweep_slots(runs),
+                "merge_rows": sum(m.rows for m in merges),
                 **configs,
             }
-            self.obs.metrics.counter(
-                "repro_mining_launches_total",
-                path=path).inc(len(layout.buckets))
-            self.obs.metrics.counter(
-                "repro_mining_sweep_slots_total",
-                path=path).inc(stats["sweep_slots"])
-            return counts, stats
+            metrics = self.obs.metrics
+            metrics.counter("repro_mining_launches_total",
+                            path=path).inc(len(layout.buckets))
+            metrics.counter("repro_mining_sweep_slots_total",
+                            path=path).inc(stats["sweep_slots"])
+            metrics.counter("repro_mining_merge_rows_total",
+                            path=path).inc(stats["merge_rows"])
+            return tuple(m.counts for m in merges), stats
 
     # -- fused single-launch path -------------------------------------------
 

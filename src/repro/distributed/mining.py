@@ -191,7 +191,7 @@ def run_mine_layout(fn, layout, *, out_cap: int = 65536,
         if on_bucket is not None:
             on_bucket(bucket)
     return merge_partial_counts(parts, merge_cap=merge_cap,
-                                warn_label="sharded bucket")
+                                warn_label="sharded bucket").counts
 
 
 def mine_layout_on_mesh(

@@ -226,3 +226,52 @@ def test_hypothesis_merge_associativity():
         assert left == right
 
     check()
+
+
+# ---------------------------------------------------------------------------
+# Front compaction: every live row lies in the first unique_mask.sum() + 1
+# rows, so the cross-bucket merge may trim a part to that prefix.
+# ---------------------------------------------------------------------------
+
+
+def _front_compact(c: CodeCounts) -> bool:
+    mask = np.asarray(c.unique_mask)
+    return not mask[int(mask.sum()) + 1:].any()
+
+
+def test_empty_counts_are_front_compact():
+    assert _front_compact(empty_counts(0, LIMBS))
+    assert _front_compact(empty_counts(16, LIMBS))
+
+
+def test_hypothesis_producers_keep_live_rows_in_front():
+    """``count_codes``, ``aggregate_zones`` and ``merge_bounded`` (at caps
+    below, at and above the live population) on tables with padding
+    rows, zero weights, cancelling pairs and no padding at all."""
+    from repro.core.aggregation import aggregate_zones
+
+    hyp = pytest.importorskip("hypothesis")
+    st = pytest.importorskip("hypothesis.strategies")
+
+    code = st.tuples(st.integers(0, 3), st.integers(0, 3))
+    row = st.tuples(code, st.integers(-3, 3))
+    table = st.lists(row, min_size=12, max_size=12).map(
+        lambda pairs: _counts_of(pairs, capacity=12))
+    zones = st.tuples(
+        st.lists(code, min_size=12, max_size=12),
+        st.lists(st.integers(0, 2), min_size=12, max_size=12),
+        st.lists(st.sampled_from([-1, 0, 1]), min_size=3, max_size=3))
+
+    @hyp.given(a=table, b=table, z=zones, cap=st.sampled_from([4, 8, 30]))
+    @hyp.settings(deadline=None)
+    def check(a, b, z, cap):
+        codes, lengths, signs = z
+        zoned = aggregate_zones(
+            jnp.asarray(np.asarray(codes, np.int32).reshape(3, 4, LIMBS)),
+            jnp.asarray(np.asarray(lengths, np.int32).reshape(3, 4)),
+            jnp.asarray(np.asarray(signs, np.int32)))
+        merged, _ = merge_bounded(a, b, cap=cap)
+        for c in (a, b, zoned, merged):
+            assert _front_compact(c)
+
+    check()

@@ -20,6 +20,8 @@ Two layers:
     "tier1", registered in conftest).
 """
 
+import warnings
+
 import numpy as np
 import pytest
 
@@ -267,6 +269,57 @@ def test_layout_overflow_names_offending_bucket():
     with pytest.warns(RuntimeWarning, match="dropped"):
         dense_got = ex.run_layout(dense, allow_overflow=True).counts
     assert _dict(got) == _dict(dense_got)
+
+
+def _front_compact(counts) -> bool:
+    """Every live row lies in the first ``unique_mask.sum() + 1`` rows."""
+    mask = np.asarray(counts.unique_mask)
+    return not mask[int(mask.sum()) + 1:].any()
+
+
+@pytest.mark.parametrize("backend,zone_chunk,agg", [
+    ("ref", 0, "legacy"),            # count_codes via aggregate_zones
+    ("ref", 4, "hierarchical"),      # the bounded chunk fold
+    ("numpy", 0, "legacy"),          # host scan, whole-batch fold
+    ("numpy", 4, "hierarchical"),    # host scan, bounded chunk merges
+], ids=["whole-batch", "bucket-fold", "host-scan", "host-scan-fold"])
+def test_bucket_tables_keep_live_rows_in_front(backend, zone_chunk, agg):
+    """Every producer that feeds the cross-bucket merge leaves its table
+    front-compact, so the merge's trim to the live prefix drops nothing."""
+    g = _powerlaw_bursty(seed=5)
+    plan = tzp.plan_zones(g, delta=12, l_max=3, omega=2)
+    lay = tzp.build_zone_layout(g, plan, layout="bucketed")
+    ex = MiningExecutor(delta=12, l_max=3, backend=backend,
+                        zone_chunk=zone_chunk, agg=agg, merge_cap=16)
+    for b in lay.buckets:
+        with warnings.catch_warnings():
+            warnings.simplefilter("ignore", RuntimeWarning)   # spills
+            part = ex.run_arrays(b.u, b.v, b.t, b.valid, b.sign)
+        assert _front_compact(part), b.label
+
+
+def test_trimmed_merge_keeps_per_bucket_fused_and_dense_equal():
+    """With the cross-bucket merge sorting each part's live prefix (fewer
+    rows than the parts hold), per-bucket == fused == dense == oracle."""
+    g = _powerlaw_bursty(seed=5)
+    delta, l_max = 12, 3
+    plan = tzp.plan_zones(g, delta=delta, l_max=l_max, omega=2)
+    buck = tzp.build_zone_layout(g, plan, layout="bucketed")
+    dense = tzp.build_zone_layout(g, plan, layout="dense")
+    ex = MiningExecutor(delta=delta, l_max=l_max, fused_backend="xla")
+    per_bucket = ex.run_layout(buck, fused=False)
+    parts = [ex.run_arrays(b.u, b.v, b.t, b.valid, b.sign)
+             for b in buck.buckets]
+    cap = sum(int(np.asarray(p.unique_mask).sum()) for p in parts) + 1
+    untrimmed = sum(cap + p.unique_mask.shape[0] for p in parts)
+    assert 0 < per_bucket.stats["merge_rows"] < untrimmed
+    fused = ex.run_layout(buck, fused=True)
+    assert fused.stats["launches"] == 1
+    got = _dict(per_bucket.counts)
+    assert got == _dict(fused.counts)
+    assert got == _dict(ex.run_layout(dense, fused=False).counts)
+    assert got == dict(oracle.count_codes(g.u, g.v, g.t, delta, l_max))
+    assert len(parts) >= 3
 
 
 # ---------------------------------------------------------------------------

@@ -283,3 +283,27 @@ def test_engine_counts_sweep_slots_and_mirrors_them(fused):
     assert obs.metrics.counter("repro_mining_sweep_slots_total",
                                path=run["path"]).value \
         == engine.stats.sweep_slots
+
+
+@pytest.mark.parametrize("fused", ["off", "on"])
+def test_engine_counts_merge_rows_and_mirrors_them(fused):
+    """The per-bucket path's cross-bucket merge rows reach the engine's
+    stats and the registry alike; the fused path has no such merge."""
+    import repro.obs as obs_mod
+
+    obs = obs_mod.enabled()
+    engine = PTMTEngine(CFG.with_updates(fused=fused, fused_backend="xla"),
+                        obs=obs)
+    g = _graph()
+    res = engine.discover(g)
+    run = res.layout["execution"]
+    engine.discover(g)
+    rows = run.get("merge_rows", 0)
+    assert engine.stats.merge_rows == 2 * rows
+    assert obs.metrics.counter("repro_mining_merge_rows_total",
+                               path=run["path"]).value \
+        == engine.stats.merge_rows
+    if fused == "off":
+        assert len(res.layout["buckets"]) > 1 and rows > 0
+    else:
+        assert "merge_rows" not in run

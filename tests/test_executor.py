@@ -287,11 +287,25 @@ def test_cross_bucket_merge_is_named_merge():
     from repro.core import executor as ex_mod
 
     a = aggregation.empty_counts(16, 2)
-    text = ex_mod._merge_part_jit.lower(a, a, cap=16).as_text(
+    part = aggregation.empty_counts(64, 2)
+    text = ex_mod._merge_part_jit.lower(a, part, cap=16, width=8).as_text(
         debug_info=True)
     scopes = _scopes(text)
     assert "merge" in scopes and "fold" not in scopes
+    # the sort covers the carry and the part's first ``width`` rows only
+    import re
+
+    sorts = re.findall(r"\}\) : \((tensor<\d+xi32>)", text)
+    assert set(sorts) == {"tensor<24xi32>"}
     # and merge_partial_counts runs through it, with an exact result
+    g, parts = _bucket_parts()
+    merged = ex_mod.merge_partial_counts(parts)
+    assert _counts_dict(merged.counts) == dict(
+        oracle.count_codes(g.u, g.v, g.t, 60, 3))
+
+
+def _bucket_parts():
+    """A bucketed layout's per-bucket count tables (more than one)."""
     g = random_graph(3, 300, 20, 2_000)
     plan = tzp.plan_zones(g, delta=60, l_max=3, omega=2)
     layout = tzp.build_zone_layout(g, plan, layout="bucketed")
@@ -299,9 +313,105 @@ def test_cross_bucket_merge_is_named_merge():
     parts = [ex.run_arrays(b.u, b.v, b.t, b.valid, b.sign)
              for b in layout.buckets]
     assert len(parts) > 1
-    merged = ex_mod.merge_partial_counts(parts)
-    assert _counts_dict(merged) == dict(
+    return g, parts
+
+
+def _table(codes, counts, rows):
+    """A ``count_codes`` table of ``rows`` rows holding these live codes
+    (the rows beyond them are all-zero padding)."""
+    from repro.core import aggregation
+
+    c = np.zeros((rows, codes.shape[1]), np.int32)
+    w = np.zeros(rows, np.int32)
+    c[:len(codes)], w[:len(codes)] = codes, counts
+    return aggregation.count_codes(c, w)
+
+
+def _split_live(part, k, pad=20):
+    """``part``'s live codes as two tables: the first ``k`` codes and the
+    rest, each with ``pad`` padding rows (so each has a padding group)."""
+    mask = np.asarray(part.unique_mask)
+    codes = np.asarray(part.codes)[mask]
+    counts = np.asarray(part.counts)[mask]
+    assert len(codes) > k
+    return [_table(codes[:k], counts[:k], k + pad),
+            _table(codes[k:], counts[k:], len(codes) - k + pad)]
+
+
+def _every_slot_live(part):
+    """``part`` re-tabled at exactly its live codes: no padding group."""
+    mask = np.asarray(part.unique_mask)
+    n = int(mask.sum())
+    table = _table(np.asarray(part.codes)[mask],
+                   np.asarray(part.counts)[mask], n)
+    assert bool(np.asarray(table.unique_mask).all())
+    return table
+
+
+TRIM_CASES = {
+    "buckets": lambda parts: parts,
+    "no-live-rows": lambda parts: parts + [_table(
+        np.zeros((0, parts[0].codes.shape[1]), np.int32),
+        np.zeros(0, np.int32), 64)],
+    "no-padding-group": lambda parts: [_every_slot_live(parts[0]),
+                                       *parts[1:]],
+    "n-plus-1-is-pow2": lambda parts: [*_split_live(parts[0], 15),
+                                       *parts[1:]],
+    "n-is-pow2": lambda parts: [*_split_live(parts[0], 16), *parts[1:]],
+    "spill-retry": lambda parts: parts,
+}
+
+
+@pytest.mark.parametrize("case", sorted(TRIM_CASES))
+def test_merge_sorts_each_parts_live_prefix(case, monkeypatch):
+    """The cross-bucket merge takes each part at ``min(rows, next_pow2(n +
+    1))`` rows, ``n`` its unique codes, counts ``cap + width`` rows a step
+    over every attempt, and stays exact: equal to the oracle."""
+    from repro.core import executor as ex_mod
+
+    g, parts = _bucket_parts()
+    parts = TRIM_CASES[case](parts)
+    steps = []
+    merge_step = ex_mod._merge_part_jit
+
+    def spy(carry, part, *, cap, width):
+        steps.append((cap, width))
+        return merge_step(carry, part, cap=cap, width=width)
+
+    monkeypatch.setattr(ex_mod, "_merge_part_jit", spy)
+    live = [int(np.asarray(p.unique_mask).sum()) for p in parts]
+    rows = [int(p.unique_mask.shape[0]) for p in parts]
+    widths = [min(r, tzp.next_pow2(n + 1)) for r, n in zip(rows, live)]
+    if case == "spill-retry":
+        with pytest.warns(RuntimeWarning, match="merge spilled"):
+            out = ex_mod.merge_partial_counts(parts, merge_cap=8)
+        assert len(steps) > len(parts)
+        assert steps[0][0] == 8
+    else:
+        out = ex_mod.merge_partial_counts(parts)
+        assert {cap for cap, _ in steps} == {sum(live) + 1}
+    assert _counts_dict(out.counts) == dict(
         oracle.count_codes(g.u, g.v, g.t, 60, 3))
+    assert len(steps) % len(parts) == 0
+    assert [w for _, w in steps] == widths * (len(steps) // len(parts))
+    assert out.rows == sum(cap + w for cap, w in steps)
+    assert widths[0] < rows[0] or case == "no-padding-group"
+    if case == "no-live-rows":
+        assert widths[-1] == 1 < rows[-1]
+    if case == "no-padding-group":
+        assert widths[0] == rows[0] == live[0]
+    if case == "n-plus-1-is-pow2":
+        assert widths[0] == live[0] + 1 == 16
+    if case == "n-is-pow2":
+        assert widths[0] == 32 and live[0] == 16
+
+
+def test_single_part_needs_no_merge():
+    from repro.core import executor as ex_mod
+
+    _, parts = _bucket_parts()
+    out = ex_mod.merge_partial_counts(parts[:1])
+    assert out.counts is parts[0] and out.rows == 0
 
 
 # ---------------------------------------------------------------------------
